@@ -34,11 +34,6 @@ pool (CPU CI runs them in interpret mode, so the numbers are a parity
     chunking (``k_blk == block size``); byte-identical by
     construction, one extra pass over the cache bytes.
 
-And two launcher rows measure cold-start hardening: ``compile_cold``
-vs ``compile_warm`` run the smoke model's first forward in a fresh
-subprocess against an empty vs pre-warmed persistent JAX compilation
-cache (``repro.launch.compile_cache``).
-
 Reported per variant: steps/s, host-sync fraction, slot occupancy,
 modelled joules/token (EnergyModel active power over the wall), KV HBM
 bytes (``pool_hbm_bytes`` — the K/V rows paging shrinks, metadata
@@ -162,56 +157,6 @@ def _kernel_rows(reps: int = 5, seed: int = 0) -> list[dict]:
     return rows
 
 
-def _compile_rows() -> list[dict]:
-    """Cold vs warm start of the smoke model's first forward in a
-    fresh subprocess: an empty persistent-compilation-cache dir, then
-    the same dir again.  The delta is what the cache buys a replica
-    restart."""
-    import subprocess
-    import tempfile
-
-    child = (
-        "import json, time\n"
-        "from repro.launch.compile_cache import enable_compilation_cache\n"
-        "enable_compilation_cache()\n"
-        "import jax, jax.numpy as jnp\n"
-        "from repro.configs import get_smoke_config\n"
-        "from repro.models import transformer as tfm\n"
-        f"cfg = get_smoke_config({ARCH!r}).replace(remat=False)\n"
-        "params = tfm.init_lm(cfg, jax.random.PRNGKey(0))\n"
-        "toks = jnp.zeros((2, 8), jnp.int32)\n"
-        "t0 = time.perf_counter()\n"
-        "out, _ = tfm.forward(cfg, params, toks)\n"
-        "out.block_until_ready()\n"
-        "print(json.dumps({'first_forward_s':"
-        " time.perf_counter() - t0}))\n"
-    )
-    rows = []
-    with tempfile.TemporaryDirectory(prefix="jaxcache-") as cache:
-        for name in ("compile_cold", "compile_warm"):
-            env = dict(os.environ,
-                       JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS",
-                                                    "cpu"),
-                       JAX_COMPILATION_CACHE_DIR=cache,
-                       PYTHONPATH=os.path.join(_REPO_ROOT, "src"))
-            t0 = time.perf_counter()
-            out = subprocess.run(
-                [sys.executable, "-c", child], env=env, cwd=_REPO_ROOT,
-                capture_output=True, text=True, timeout=600)
-            wall = time.perf_counter() - t0
-            if out.returncode != 0:      # surface the child's stderr
-                raise RuntimeError(f"{name} probe failed:\n{out.stderr}")
-            payload = json.loads(out.stdout.strip().splitlines()[-1])
-            rows.append({
-                "variant": name,
-                "layout": "launcher",
-                "first_forward_s": round(payload["first_forward_s"], 3),
-                "process_wall_s": round(wall, 3),
-                "cache_entries": len(os.listdir(cache)),
-            })
-    return rows
-
-
 def run(n: int = N_REQUESTS, n_slots: int = N_SLOTS,
         seed: int = 0) -> list[dict]:
     import jax
@@ -295,7 +240,6 @@ def run(n: int = N_REQUESTS, n_slots: int = N_SLOTS,
             "generated": [list(r.generated) for r in reqs],
         })
     rows += _kernel_rows(seed=seed)
-    rows += _compile_rows()
     return rows
 
 
@@ -355,16 +299,6 @@ def check(rows) -> dict:
         "paged_native_not_slower": (
             native["us_per_call"] <= 1.3 * shim["us_per_call"]),
     })
-    # launcher: persistent-compilation-cache cold vs warm start
-    cold, warm = by["compile_cold"], by["compile_warm"]
-    out.update({
-        "cold_start_first_forward_s": cold["first_forward_s"],
-        "warm_start_first_forward_s": warm["first_forward_s"],
-        "warm_start_speedup_x": round(
-            cold["first_forward_s"]
-            / max(warm["first_forward_s"], 1e-9), 2),
-        "compile_cache_populated": cold["cache_entries"] > 0,
-    })
     slim = [{k: v for k, v in r.items() if k != "generated"}
             for r in rows]
     with open(os.path.join(_REPO_ROOT, "BENCH_continuous.json"),
@@ -392,8 +326,7 @@ def main(argv) -> int:
                                 "paged_slots_ge_contiguous",
                                 "paged_slots_gain_ge_2x",
                                 "paged_native_matches_shim",
-                                "paged_native_not_slower",
-                                "compile_cache_populated")
+                                "paged_native_not_slower")
                     if not chk[k]]
         if failures:
             print(f"SMOKE FAIL: {failures}", file=sys.stderr)

@@ -73,12 +73,10 @@ _BENCHES = [
 
 
 def main() -> None:
-    # cold-start hardening: honor $JAX_COMPILATION_CACHE_DIR so a
-    # repeat of the full harness skips every XLA re-compile
+    # cold-start hardening: a repeat of the full harness reads every
+    # XLA compile from the persistent cache
     from repro.launch.compile_cache import enable_compilation_cache
-    cache_dir = enable_compilation_cache()
-    if cache_dir:
-        print(f"# compilation cache: {cache_dir}")
+    print(f"# compilation cache: {enable_compilation_cache()}")
     os.makedirs(OUT, exist_ok=True)
     print("name,us_per_call,derived")
     failures = 0

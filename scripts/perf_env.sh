@@ -28,9 +28,9 @@ export TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD="${TCMALLOC_LARGE_ALLOC_REPORT_THRE
 # quiet the TF/XLA C++ log spam that dominates cold-start stderr
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
 
-# persistent compilation cache: cold start compiles once per deploy,
-# warm starts read from disk (repro.launch.compile_cache picks this up)
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$HOME/.cache/repro-jax-cache}"
+# the persistent compilation cache is not set here: the launchers use
+# $JAX_COMPILATION_CACHE_DIR when the caller sets it, else
+# <repo>/.jax_cache (repro.launch.compile_cache)
 
 # keep the fused decode window as ONE outer-while step for profilers
 # (olmax: 0 = entry, 1 = outer while)

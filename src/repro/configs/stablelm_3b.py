@@ -1,4 +1,4 @@
-"""stablelm-3b — dense MHA decoder [hf:stabilityai/stablelm-2-1_6b family].
+"""stablelm-3b — dense MHA decoder [hf:stabilityai/stablelm-3b-4e1t].
 
 32 layers, d_model 2560, 32 heads (MHA, kv=32), d_ff 6912,
 vocab 50 304, partial rotary (25 %), LayerNorm.
@@ -20,7 +20,7 @@ CONFIG = ModelConfig(
     norm="layernorm",
     tie_embeddings=False,
     rope_theta=10_000.0,
-    source="hf:stabilityai/stablelm-2-1_6b",
+    source="hf:stabilityai/stablelm-3b-4e1t",
 )
 
 

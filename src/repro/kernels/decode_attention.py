@@ -2,9 +2,10 @@
 
 The dominant op of the decode_32k / long_500k shapes: q [B, H, hd]
 against k/v [B, K, S, hd] with per-slot absolute positions (supports
-ring-buffered sliding-window caches).  Grid (B, H, kv_blocks), KV
-innermost, online softmax in VMEM scratch.  The cache never leaves HBM
-except for the [k_blk, hd] tile streamed through VMEM — this kernel is
+ring-buffered sliding-window caches).  Grid (B, kv_blocks), KV
+innermost; each step takes every head of one slot against one
+[K, k_blk, hd] tile, online softmax in VMEM scratch.  The cache never
+leaves HBM except for the tile streamed through VMEM — this kernel is
 purely HBM-bandwidth bound, which is exactly what the roofline says.
 
 Two paged entry points serve the vLLM-style shared block pool:
@@ -13,7 +14,7 @@ Two paged entry points serve the vLLM-style shared block pool:
     slot's ``block_table`` row is scalar-prefetched
     (``pltpu.PrefetchScalarGridSpec``) and every grid step's HBM→VMEM
     DMA is redirected through it by the BlockSpec index_map, so the
-    kernel streams ``[block_size, hd]`` tiles straight out of the
+    kernel streams ``[block_size, K, hd]`` blocks straight out of the
     shared pool.  No gather, no contiguous copy — the pool's K/V bytes
     cross HBM exactly once per decode step.
   - :func:`paged_decode_attention_shim` — the materialised-gather
@@ -40,53 +41,85 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.runtime import resolve_interpret
 
 _NEG = -1e30
+# rows of K/V per grid step (summed over kv heads) when the caller
+# leaves ``k_blk`` unset: 2048 x hd bf16 rows keep the double-buffered
+# K/V tiles plus their f32 copies well inside the default scoped VMEM
+_TILE_ROWS = 2048
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, cur_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, scale: float, window: int,
-                   k_blk: int, skv: int):
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _decode_body(q_ref, k_ref, v_ref, pos_ref, o_ref, m_ref, l_ref,
+                 acc_ref, *, cur, scale: float, window: int,
+                 heads_minor: bool):
+    """One grid step (b, ki): every head of slot b against one KV tile.
+
+    q_ref [1, K, G, hd]; k_ref/v_ref [1, K, T, hd] (contiguous) or
+    [1, T, K, hd] (``heads_minor``: a pool block); pos_ref [1, nk, T]
+    holds the slot's whole position row, of which step ki reads row ki.
+    Both kernels run this body, so at ``k_blk == block_size`` the
+    contiguous and paged kernels execute the identical online-softmax
+    schedule."""
+    ki = pl.program_id(1)
+    nk = pl.num_programs(1)
 
     @pl.when(ki == 0)
     def _init():
-        m_ref[:] = jnp.full(m_ref.shape, _NEG, jnp.float32)
-        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[:, :] = jnp.zeros(acc_ref.shape, jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale           # [1, hd]
-    k = k_ref[0, 0].astype(jnp.float32)                   # [k_blk, hd]
-    v = v_ref[0, 0].astype(jnp.float32)
-    kv_pos = pos_ref[0]                                   # [k_blk]
-    cur = cur_ref[0]                                      # scalar int32
+    q = q_ref[0].astype(jnp.float32) * scale              # [K, G, hd]
+    k = k_ref[0].astype(jnp.float32)
+    v = v_ref[0].astype(jnp.float32)
+    if heads_minor:                       # [T, K, hd] -> [K, T, hd]
+        k = jnp.swapaxes(k, 0, 1)
+        v = jnp.swapaxes(v, 0, 1)
+    kv_pos = pos_ref[0, pl.ds(ki, 1), :]                  # [1, T]
 
-    s = (q @ k.T)[0]                                      # [k_blk]
-    col = ki * k_blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    ok = (col < skv) & (kv_pos >= 0) & (kv_pos <= cur)
+    s = jnp.einsum("kgd,ktd->kgt", q, k)                  # [K, G, T]
+    ok = (kv_pos >= 0) & (kv_pos <= cur)
     if window:
         ok = ok & (cur - kv_pos < window)
-    s = jnp.where(ok, s, _NEG)
+    s = jnp.where(ok[None], s, _NEG)
 
-    m_old = m_ref[0]
-    m_new = jnp.maximum(m_old, jnp.max(s))
+    m_old = m_ref[...]                                    # [K, G, 1]
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
     corr = jnp.exp(m_old - m_new)
     p = jnp.exp(s - m_new)
-    l_ref[0] = l_ref[0] * corr + jnp.sum(p)
-    acc_ref[0, :] = acc_ref[0, :] * corr + p @ v
-    m_ref[0] = m_new
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jnp.einsum("kgt,ktd->kgd", p, v)
+    m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _emit():
-        l = jnp.maximum(l_ref[0], 1e-30)
-        o_ref[0, 0, 0] = (acc_ref[0, :] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _decode_kernel(cur_ref, q_ref, k_ref, v_ref, pos_ref, o_ref, m_ref,
+                   l_ref, acc_ref, *, scale: float, window: int):
+    _decode_body(q_ref, k_ref, v_ref, pos_ref, o_ref, m_ref, l_ref,
+                 acc_ref, cur=cur_ref[pl.program_id(0)], scale=scale,
+                 window=window, heads_minor=False)
+
+
+def _scratch(K: int, G: int, hd: int) -> list:
+    return [pltpu.VMEM((K, G, 1), jnp.float32),
+            pltpu.VMEM((K, G, 1), jnp.float32),
+            pltpu.VMEM((K, G, hd), jnp.float32)]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "k_blk", "interpret"))
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      kv_pos: jax.Array, cur_pos: jax.Array, *,
-                     window: int = 0, k_blk: int = 512,
+                     window: int = 0, k_blk: int | None = None,
                      interpret: bool | None = None) -> jax.Array:
     """q [B,H,hd]; k/v [B,K,S,hd]; kv_pos [B,S]; cur_pos [B] -> [B,H,hd].
+
+    Grid (B, kv_blocks): each step streams a [K, k_blk, hd] tile of
+    every kv head.  ``k_blk=None`` sizes it to ``_TILE_ROWS`` rows in
+    all.  ``cur_pos`` rides in SMEM as a scalar-prefetch operand, and
+    ``kv_pos`` is read whole per slot as [nk, k_blk], so every block
+    spans its array's last two dims (the TPU tiling rule).
 
     ``interpret=None`` resolves to compiled-on-TPU / interpreted
     elsewhere (``repro.kernels.runtime.default_interpret``)."""
@@ -96,6 +129,8 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     G = H // K
     scale = 1.0 / math.sqrt(hd)
 
+    if k_blk is None:
+        k_blk = max(8, min(512, _TILE_ROWS // K) // 8 * 8)
     k_blk = min(k_blk, max(S, 8))
     nk = -(-S // k_blk)
     pad = nk * k_blk - S
@@ -103,28 +138,30 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     pp = jnp.pad(kv_pos, ((0, 0), (0, pad)), constant_values=-1)
 
-    kernel = functools.partial(_decode_kernel, scale=scale, window=window,
-                               k_blk=k_blk, skv=S)
+    kernel = functools.partial(_decode_kernel, scale=scale, window=window)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, nk),
+        in_specs=[
+            pl.BlockSpec((1, K, G, hd), lambda b, ki, cur: (b, 0, 0, 0)),
+            pl.BlockSpec((1, K, k_blk, hd),
+                         lambda b, ki, cur: (b, 0, ki, 0)),
+            pl.BlockSpec((1, K, k_blk, hd),
+                         lambda b, ki, cur: (b, 0, ki, 0)),
+            pl.BlockSpec((1, nk, k_blk), lambda b, ki, cur: (b, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, K, G, hd),
+                               lambda b, ki, cur: (b, 0, 0, 0)),
+        scratch_shapes=_scratch(K, G, hd),
+    )
     out = pl.pallas_call(
         kernel,
-        grid=(B, H, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, hd), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, k_blk, hd),
-                         lambda b, h, ki, G=G: (b, h // G, ki, 0)),
-            pl.BlockSpec((1, 1, k_blk, hd),
-                         lambda b, h, ki, G=G: (b, h // G, ki, 0)),
-            pl.BlockSpec((1, k_blk), lambda b, h, ki: (b, ki)),
-            pl.BlockSpec((1,), lambda b, h, ki: (b,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, hd), lambda b, h, ki: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((1,), jnp.float32),
-                        pltpu.VMEM((1,), jnp.float32),
-                        pltpu.VMEM((1, hd), jnp.float32)],
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
-    )(q[:, :, None, :], kp, vp, pp, cur_pos.astype(jnp.int32))
-    return out[:, :, 0, :]
+    )(cur_pos.astype(jnp.int32), q.reshape(B, K, G, hd), kp, vp,
+      pp.astype(jnp.int32).reshape(B, nk, k_blk))
+    return out.reshape(B, H, hd)
 
 
 def gather_block_views(k_pool: jax.Array, v_pool: jax.Array,
@@ -164,50 +201,20 @@ def gather_block_views(k_pool: jax.Array, v_pool: jax.Array,
 # paged flash-decode — TABLE-NATIVE kernel (scalar-prefetched DMA)
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(tbl_ref, q_ref, k_ref, v_ref, pos_ref, cur_ref, o_ref,
+def _paged_kernel(tbl_ref, cur_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float, window: int):
-    """One grid step = one mapped pool block of the slot.
+    """One grid step = one mapped pool block of the slot, all heads.
 
     ``tbl_ref`` is the scalar-prefetched block table — the kernel body
     never touches it; the BlockSpec index_maps already used it to
     redirect this step's HBM→VMEM DMA, so ``k_ref``/``v_ref`` hold the
-    [bs, hd] tile of pool block ``tbl[b, ki]``.  The math is the exact
-    online-softmax schedule of ``_decode_kernel`` at k_blk == bs (no
-    pad column mask needed: the paged pos array is block-aligned by
-    construction), which is what makes the shim byte-identical."""
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[:] = jnp.full(m_ref.shape, _NEG, jnp.float32)
-        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[:, :] = jnp.zeros(acc_ref.shape, jnp.float32)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale           # [1, hd]
-    k = k_ref[0, :, 0].astype(jnp.float32)                # [bs, hd]
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    kv_pos = pos_ref[0]                                   # [bs]
-    cur = cur_ref[0]                                      # scalar int32
-
-    s = (q @ k.T)[0]                                      # [bs]
-    ok = (kv_pos >= 0) & (kv_pos <= cur)
-    if window:
-        ok = ok & (cur - kv_pos < window)
-    s = jnp.where(ok, s, _NEG)
-
-    m_old = m_ref[0]
-    m_new = jnp.maximum(m_old, jnp.max(s))
-    corr = jnp.exp(m_old - m_new)
-    p = jnp.exp(s - m_new)
-    l_ref[0] = l_ref[0] * corr + jnp.sum(p)
-    acc_ref[0, :] = acc_ref[0, :] * corr + p @ v
-    m_ref[0] = m_new
-
-    @pl.when(ki == nk - 1)
-    def _emit():
-        l = jnp.maximum(l_ref[0], 1e-30)
-        o_ref[0, 0, 0] = (acc_ref[0, :] / l).astype(o_ref.dtype)
+    [bs, K, hd] rows of pool block ``tbl[b, ki]``.  The math is
+    ``_decode_body``, the contiguous kernel's own, which is what makes
+    the shim byte-identical at k_blk == bs."""
+    del tbl_ref
+    _decode_body(q_ref, k_ref, v_ref, pos_ref, o_ref, m_ref, l_ref,
+                 acc_ref, cur=cur_ref[pl.program_id(0)], scale=scale,
+                 window=window, heads_minor=True)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -223,11 +230,12 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     block; kv_pos [B, MB*bs] per-slot absolute positions (-1 = empty);
     cur_pos [B] -> [B,H,hd].
 
-    The block table rides in as a scalar-prefetch operand
-    (``pltpu.PrefetchScalarGridSpec``): it is resident in SMEM before
-    the first grid step, and the k/v BlockSpec index_maps read
-    ``tbl[b, ki]`` to aim each step's HBM→VMEM DMA at the slot's
-    ki-th mapped pool block.  The shared pool is therefore consumed
+    The block table and ``cur_pos`` ride in as scalar-prefetch
+    operands (``pltpu.PrefetchScalarGridSpec``): they are resident in
+    SMEM before the first grid step, and the k/v BlockSpec index_maps
+    read ``tbl[b, ki]`` to aim each step's HBM→VMEM DMA at the slot's
+    ki-th mapped pool block — all K heads of it, so the block spans
+    the pool's last two dims.  The shared pool is therefore consumed
     IN PLACE — no materialised gather, no contiguous copy, no second
     pass over the cache bytes.  The grid's KV chunk is the pool block
     size (DMAs must land on pool-block boundaries; a k_blk knob would
@@ -256,34 +264,30 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 
     kernel = functools.partial(_paged_kernel, scale=scale, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, H, nk),
+        num_scalar_prefetch=2,
+        grid=(B, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda b, h, ki, tbl: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, ki, tbl, G=G:
-                         (tbl[b, ki], 0, h // G, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, ki, tbl, G=G:
-                         (tbl[b, ki], 0, h // G, 0)),
-            pl.BlockSpec((1, bs), lambda b, h, ki, tbl: (b, ki)),
-            pl.BlockSpec((1,), lambda b, h, ki, tbl: (b,)),
+            pl.BlockSpec((1, K, G, hd),
+                         lambda b, ki, tbl, cur: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bs, K, hd),
+                         lambda b, ki, tbl, cur: (tbl[b, ki], 0, 0, 0)),
+            pl.BlockSpec((1, bs, K, hd),
+                         lambda b, ki, tbl, cur: (tbl[b, ki], 0, 0, 0)),
+            pl.BlockSpec((1, nk, bs), lambda b, ki, tbl, cur: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, hd),
-                               lambda b, h, ki, tbl: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((1,), jnp.float32),
-                        pltpu.VMEM((1,), jnp.float32),
-                        pltpu.VMEM((1, hd), jnp.float32)],
+        out_specs=pl.BlockSpec((1, K, G, hd),
+                               lambda b, ki, tbl, cur: (b, 0, 0, 0)),
+        scratch_shapes=_scratch(K, G, hd),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), q[:, :, None, :], k_pool, v_pool,
-      kv_pos, cur_pos.astype(jnp.int32))
-    return out[:, :, 0, :]
+    )(block_table.astype(jnp.int32), cur_pos.astype(jnp.int32),
+      q.reshape(B, K, G, hd), k_pool, v_pool,
+      kv_pos.astype(jnp.int32).reshape(B, nk, bs))
+    return out.reshape(B, H, hd)
 
 
 @functools.partial(jax.jit,
@@ -291,7 +295,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 def paged_decode_attention_shim(q: jax.Array, k_pool: jax.Array,
                                 v_pool: jax.Array, block_table: jax.Array,
                                 kv_pos: jax.Array, cur_pos: jax.Array, *,
-                                window: int = 0, k_blk: int = 512,
+                                window: int = 0,
+                                k_blk: int | None = None,
                                 interpret: bool | None = None
                                 ) -> jax.Array:
     """Flash-decode over a paged block pool — block-table gather SHIM.

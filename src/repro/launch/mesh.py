@@ -10,10 +10,10 @@ module never touches jax device state.
 """
 from __future__ import annotations
 
-import jax
-
-
 import math
+
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,14 +25,16 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, have {len(devices)} — "
             "launch via launch/dryrun.py which forces 512 host devices")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return jax.make_mesh(shape, axes, devices=devices[:need],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh(*, data: int = 1, model: int = 1):
     """Small mesh over however many (host) devices exist — used by the
     CPU integration tests (subprocesses set
     --xla_force_host_platform_device_count)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def batch_axes(mesh) -> tuple:

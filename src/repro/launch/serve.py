@@ -48,7 +48,7 @@ import json
 import jax
 import numpy as np
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.configs import ARCH_IDS, ModelConfig, get_smoke_config
 from repro.core import (AdaptiveThreshold, AdmissionController,
                         CostWeights, DecayingThreshold, LatencyModel)
 from repro.models import distilbert
@@ -157,7 +157,9 @@ def _arrivals(args, labels, payloads=None):
                             payloads=payloads, labels=labels)
 
 
-def serve_classifier(args) -> dict:
+def serve_classifier(args) -> tuple[dict, list]:
+    """One classifier serving run on ``--path``; returns the summary
+    and the responses."""
     tracker = Tracker(root=args.runs)
     run = tracker.start_run(f"serve-{args.controller}-{args.path}")
     carbon = CarbonTracker(region=args.region)
@@ -212,10 +214,10 @@ def serve_classifier(args) -> dict:
                     tracer=tracer, metrics=metrics)
     if path == "gated-in-graph":
         carbon.start()
-        server.serve(reqs)
+        responses = server.serve(reqs)
         carbon.stop(args.requests)
     else:
-        server.serve(reqs)
+        responses = server.serve(reqs)
     summary = server.summary()
     summary["controller"] = args.controller
     summary["path"] = path
@@ -231,7 +233,7 @@ def serve_classifier(args) -> dict:
     run.log_artifact("summary.json", summary)
     run.log_artifact("carbon.json", carbon.report())
     run.finish()
-    return summary
+    return summary, responses
 
 
 def serve_fleet(args) -> dict:
@@ -412,24 +414,31 @@ def _apply_sampling_cfg(cfg, args):
     return cfg.replace(**fields)
 
 
-def serve_generate(args) -> dict:
-    cfg = get_smoke_config(args.arch).replace(
+def serve_generate(args, cfg: ModelConfig | None = None, *,
+                   max_seq: int = 128,
+                   prompt_len: int = 16) -> tuple[dict, list, Server]:
+    """Continuous-decode generation of ``--requests`` seeded prompts of
+    ``prompt_len`` tokens; returns the summary, the responses and the
+    server.  ``cfg`` is the model (default: the smoke config of
+    ``--arch``); the attention, KV-pool and sampling flags apply on
+    top of it either way."""
+    cfg = (cfg or get_smoke_config(args.arch)).replace(
         attn_impl=args.attn_impl,
         kv_block_size=args.kv_block_size,
         kv_pool_blocks=args.kv_pool_blocks)
     cfg = _apply_sampling_cfg(cfg, args)
     params = tfm.init_lm(cfg, jax.random.PRNGKey(args.seed))
     engine = ContinuousBatchingEngine(cfg, params, n_slots=args.slots,
-                                     max_seq=128,
+                                     max_seq=max_seq,
                                      draft_depth=args.draft_depth)
     rng = np.random.default_rng(args.seed)
-    prompts = rng.integers(0, cfg.vocab,
-                           size=(args.requests, 16)).astype(np.int32)
+    prompts = rng.integers(0, cfg.vocab, size=(args.requests, prompt_len))
+    prompts = prompts.astype(np.int32)
     ctrl = make_controller(args.controller, weights=args.weights,
                            target_rate=args.target_rate)
     tracer, metrics, audit = make_observability(args)
-    server = Server(ContinuousEngineAdapter(engine, prompt_len=16),
-                    ServerConfig(path="continuous-decode"),
+    port = ContinuousEngineAdapter(engine, prompt_len=prompt_len)
+    server = Server(port, ServerConfig(path="continuous-decode"),
                     middleware=[AdmissionMiddleware(ctrl)],
                     tracer=tracer, metrics=metrics)
     reqs = [InferRequest(rid=i, arrival_s=0.001 * i, payload=prompts[i],
@@ -467,10 +476,10 @@ def serve_generate(args) -> dict:
         sample=(responses[0].output[:8] if responses else []),
         **decode_stats)
     print(json.dumps(summary, default=str, indent=2))
-    return summary
+    return summary, responses, server
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["classify", "generate"],
                     default="classify")
@@ -547,12 +556,6 @@ def main():
                     help="measured-energy reader for the drift audit "
                          "(modelled vs measured joules); the default "
                          "process-time proxy works everywhere")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent JAX compilation-cache directory "
-                         "(cold-start hardening: compiles after the "
-                         "first run become disk reads); default is "
-                         "$JAX_COMPILATION_CACHE_DIR, unset = off, "
-                         "'' = force off")
     ap.add_argument("--runs", default="runs")
     ap.add_argument("--seed", type=int, default=0)
     # fleet mode
@@ -600,10 +603,13 @@ def main():
                          "queued work past it is shed as a rejection-"
                          "with-reason (default: the chaos scenario's "
                          "deadline, or none)")
+    return ap
+
+
+def main():
+    ap = build_parser()
     args = ap.parse_args()
-    cache_dir = enable_compilation_cache(args.compile_cache)
-    if cache_dir:
-        print(f"compilation cache: {cache_dir}")
+    print(f"compilation cache: {enable_compilation_cache()}")
     if args.chaos:
         args.fleet = True
     if args.chaos_seed is None:
@@ -645,7 +651,7 @@ def main():
     if args.mode == "generate":
         serve_generate(args)
         return
-    summary = serve_classifier(args)
+    summary, _ = serve_classifier(args)
     print(json.dumps(summary, indent=2))
 
 

@@ -360,6 +360,12 @@ class ContinuousEngineAdapter:
         self._pending_dt = 0.0
         self._win_free_at = 0.0
 
+    @property
+    def session(self):
+        """The live :class:`DecodeSession` (None before the first
+        submit of a serving run)."""
+        return self._session
+
     def _ensure_session(self):
         if self._session is None:
             self._session = self.engine.start_session(self.prompt_len)

@@ -1286,18 +1286,14 @@ class DecodeSession:
             self._pool = self._pool._replace(
                 block_table=jnp.asarray(self._table_h))
             self._table_dirty = False
-        sargs = (jnp.asarray(self._skey_h), jnp.asarray(self._temp_h),
-                 jnp.asarray(self._topk_h), jnp.asarray(self._topp_h))
         spec = eng.draft_depth > 0
         if spec:
             depth = eng.current_depth()
             self.last_depth = depth
-            sargs = sargs + (jnp.asarray(depth, jnp.int32),)
         t0 = time.perf_counter()
         (self._pool, self._cur_tok, self._pos, self._active,
          self._remaining, toks, emitted) = eng._step_k(
-            eng.params, self._pool, self._cur_tok, self._pos,
-            self._active, self._remaining, self._eos, *sargs)
+            *self._window_args())
         jax.block_until_ready(toks)
         self.device_s += time.perf_counter() - t0
         # ONE host sync per window: token/emission pulls — [k,B], or
@@ -1340,6 +1336,23 @@ class DecodeSession:
                     self._free_slot_blocks(s)
         self._active_host = active_h
         return completed
+
+    def _window_args(self) -> tuple:
+        """Operands of the fused decode window for the current state."""
+        eng = self.engine
+        args = (eng.params, self._pool, self._cur_tok, self._pos,
+                self._active, self._remaining, self._eos,
+                jnp.asarray(self._skey_h), jnp.asarray(self._temp_h),
+                jnp.asarray(self._topk_h), jnp.asarray(self._topp_h))
+        if eng.draft_depth > 0:
+            args += (jnp.asarray(self.last_depth, jnp.int32),)
+        return args
+
+    def window_hlo(self) -> str:
+        """The fused decode window as compiled for the current backend
+        (optimised HLO text) — shows which kernels the window runs."""
+        return self.engine._step_k.lower(
+            *self._window_args()).compile().as_text()
 
     # -- reporting ----------------------------------------------------------
     def stats(self) -> dict:

@@ -21,7 +21,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def _run(code: str) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
@@ -39,7 +39,8 @@ from repro.configs import get_smoke_config
 from repro.launch import sharding as shd
 from repro.models import transformer as tfm
 from repro.training import AdamW, make_train_step
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 """
 
 
@@ -142,7 +143,7 @@ def test_production_mesh_lowering_sample():
     complete matrix lives in results/dryrun (launch/dryrun.py)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch",
          "stablelm-3b", "--shape", "decode_32k", "--mesh", "single",

@@ -43,7 +43,7 @@ def _logits(seed: int, b: int = 1, v: int = 37) -> jnp.ndarray:
 # masking algebra
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 10_000), k=st.integers(0, 48))
 def test_top_k_keeps_exactly_k(seed, k):
     """top_k_mask leaves exactly min(k, V) finite entries (k=0 = all),
@@ -60,7 +60,7 @@ def test_top_k_keeps_exactly_k(seed, k):
         assert raw[finite].min() >= raw[~finite].max()
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 10_000),
        p=st.floats(0.05, 1.0))
 def test_top_p_minimal_covering_prefix(seed, p):
@@ -88,7 +88,7 @@ def test_top_p_minimal_covering_prefix(seed, p):
         assert probs[keep].min() >= probs[~keep].max() - 1e-12
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 10_000), b=st.integers(1, 5))
 def test_temperature_zero_is_argmax_bitwise(seed, b):
     """T=0 rows return jnp.argmax over the RAW logits regardless of
@@ -104,7 +104,7 @@ def test_temperature_zero_is_argmax_bitwise(seed, b):
                           np.asarray(jnp.argmax(logits, -1), np.int32))
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 10_000),
        temp=st.floats(0.1, 2.0),
        k=st.integers(0, 20),
