@@ -159,6 +159,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(cur_pos.astype(jnp.int32), q.reshape(B, K, G, hd), kp, vp,
       pp.astype(jnp.int32).reshape(B, nk, k_blk))
     return out.reshape(B, H, hd)
@@ -284,6 +285,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_table.astype(jnp.int32), cur_pos.astype(jnp.int32),
       q.reshape(B, K, G, hd), k_pool, v_pool,
       kv_pos.astype(jnp.int32).reshape(B, nk, bs))

@@ -110,5 +110,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         pltpu.VMEM((q_blk,), jnp.float32),
                         pltpu.VMEM((q_blk, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_attention",
     )(qp, kp, vp)
     return out[:, :, :Sq]

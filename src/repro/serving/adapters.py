@@ -345,7 +345,6 @@ class ContinuousEngineAdapter:
     _by_rid: dict = field(default_factory=dict, init=False)
     _free_at: float = field(default=0.0, init=False)
     _pending_dt: float = field(default=0.0, init=False)
-    _win_free_at: float = field(default=0.0, init=False)
 
     def capabilities(self) -> EngineCapabilities:
         return EngineCapabilities(name="continuous", kind="generate",
@@ -358,7 +357,6 @@ class ContinuousEngineAdapter:
         self._by_rid.clear()
         self._free_at = 0.0
         self._pending_dt = 0.0
-        self._win_free_at = 0.0
 
     @property
     def session(self):
@@ -366,9 +364,10 @@ class ContinuousEngineAdapter:
         submit of a serving run)."""
         return self._session
 
-    def _ensure_session(self):
+    def _ensure_session(self, ctx):
         if self._session is None:
-            self._session = self.engine.start_session(self.prompt_len)
+            self._session = self.engine.start_session(
+                self.prompt_len, tracer=ctx.tracer)
         return self._session
 
     def load(self) -> LoadState:
@@ -402,38 +401,21 @@ class ContinuousEngineAdapter:
                         eos_id=meta.get("eos_id"),
                         sampling=getattr(req, "sampling", None))
         self._by_rid[req.rid] = req
-        self._ensure_session().push(gr)
+        self._ensure_session(ctx).push(gr)
         return []
 
-    def _advance_once(self, now: float, ctx=None) -> list[Completion]:
-        tracer = ctx.tracer if ctx is not None else None
-        trace_on = tracer is not None and tracer.enabled
-        if trace_on:
-            s = self._session
-            c0 = self.engine.decode_compile_count
-            syncs0, steps0 = s.host_syncs, s.decode_steps
+    def _advance_once(self, now: float, ctx) -> list[Completion]:
+        # the session scopes its own window (``step.window``); the
+        # adapter marks only the compiles it triggered
+        tracer = ctx.tracer
+        c0 = self.engine.decode_compile_count
         t0 = time.perf_counter()
         finished = self._session.advance()
-        dt = time.perf_counter() - t0
-        self._pending_dt += dt
-        if trace_on:
-            # one fused lax.scan window = one host sync; the span sits
-            # on its own device track so no-completion windows stay
-            # visible (the execute track only shows completing ones).
-            # Reads only counters advance() already synced — tracing
-            # must never add a host sync of its own.
-            wstart = max(now, self._win_free_at)
-            wfinish = wstart + dt
-            self._win_free_at = wfinish
-            compiles = self.engine.decode_compile_count - c0
-            tracer.span("decode.window", wstart, wfinish,
-                        resource="decode.device",
-                        host_syncs=s.host_syncs - syncs0,
-                        decode_steps=s.decode_steps - steps0,
-                        active=s.n_active, finished=len(finished))
-            if compiles:
-                tracer.event("xla.compile", wstart,
-                             resource="decode.device", count=compiles)
+        self._pending_dt += time.perf_counter() - t0
+        compiles = self.engine.decode_compile_count - c0
+        if compiles and tracer.enabled:
+            tracer.event("xla.compile", resource="decode.device",
+                         count=compiles)
         if not finished:
             # busy time of windows that completed nothing is folded
             # into the next completing window's span

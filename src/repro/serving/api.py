@@ -485,6 +485,10 @@ class Server:
         responses COMPLETED by this arrival (possibly none — e.g. the
         batcher absorbing the request, or several flushed batches)."""
         self._ensure_open()
+        with self.ctx.tracer.scope("server.push"):
+            return self._push(req)
+
+    def _push(self, req) -> list[InferResponse]:
         ctx, caps = self.ctx, self._caps
         n0 = len(self._out)
         now = float(req.arrival_s)
@@ -510,32 +514,34 @@ class Server:
                                 kind=getattr(req, "kind", "classify"))
             self._roots[req.rid] = root
 
-        for mw in self.middleware:
-            mw.on_enqueue(req, ctx)
-
-        # proxy triage (cheap uncertainty signal; busy-time cost)
-        tri = self.engine.triage(req, now, ctx)
-        ctx.busy_s += tri.cost_s
-        if tracer.enabled:
-            tracer.span("triage", now, now + tri.cost_s, parent=root,
-                        L=tri.L, cost_s=tri.cost_s)
-
-        # admission: last non-None middleware decision wins;
-        # in-graph engines gate on device instead
-        decision = None
-        if not caps.in_graph_admission:
+        # triage and the middleware's decision
+        with tracer.scope("server.admit"):
             for mw in self.middleware:
-                d = mw.on_triage(req, tri, ctx)
-                if d is not None:
-                    decision = d
-        if decision is not None:
-            self._decisions[req.rid] = decision
-            for mw in self.middleware:
-                mw.on_decision(req, decision, ctx)
+                mw.on_enqueue(req, ctx)
+
+            # proxy triage (cheap uncertainty signal; busy-time cost)
+            tri = self.engine.triage(req, now, ctx)
+            ctx.busy_s += tri.cost_s
             if tracer.enabled:
-                tracer.event("admission", now, parent=root,
-                             admit=bool(decision.admit),
-                             J=float(decision.J), tau=float(decision.tau))
+                tracer.span("triage", now, now + tri.cost_s, parent=root,
+                            L=tri.L, cost_s=tri.cost_s)
+
+            # admission: last non-None middleware decision wins;
+            # in-graph engines gate on device instead
+            decision = None
+            if not caps.in_graph_admission:
+                for mw in self.middleware:
+                    d = mw.on_triage(req, tri, ctx)
+                    if d is not None:
+                        decision = d
+            if decision is not None:
+                self._decisions[req.rid] = decision
+                for mw in self.middleware:
+                    mw.on_decision(req, decision, ctx)
+                if tracer.enabled:
+                    tracer.event("admission", now, parent=root,
+                                 admit=bool(decision.admit),
+                                 J=float(decision.J), tau=float(decision.tau))
 
         if decision is not None and not decision.admit:
             # "skip or respond from cache": the proxy answers
@@ -569,11 +575,12 @@ class Server:
         their batching deadlines."""
         self._ensure_open()
         ctx = self.ctx
-        n0 = len(self._out)
-        ctx.now = max(ctx.now, float(now))
-        self._absorb(self.engine.step(ctx.now, ctx), ctx,
-                     self._decisions, self._out)
-        return self._out[n0:]
+        with ctx.tracer.scope("server.poke"):
+            n0 = len(self._out)
+            ctx.now = max(ctx.now, float(now))
+            self._absorb(self.engine.step(ctx.now, ctx), ctx,
+                         self._decisions, self._out)
+            return self._out[n0:]
 
     def drain_now(self, now: float | None = None) -> list[InferResponse]:
         """Flush ALL queued work at ``now`` without closing the session
@@ -750,54 +757,58 @@ class Server:
             resp.energy_j, path=resp.path, engine=engine)
 
     def _absorb(self, completions, ctx, decisions, out) -> None:
+        if not completions:
+            return
         tracer = ctx.tracer
-        for comp in completions or ():
-            dt = comp.t_finish - comp.t_start
-            ctx.busy_s += dt
-            j_total = ctx.energy_model.p_active * dt
-            if tracer.enabled:
-                # service occupancy on the engine's line: one slice per
-                # completion, on a per-(replica, path) resource track
-                attrs = {"batch": comp.size}
-                flush = comp.extras.get("flush") if comp.extras else None
-                if flush:
-                    attrs["flush"] = flush
-                res = (f"{self.name}:{comp.path}" if self.name
-                       else comp.path)
-                tracer.span("execute", comp.t_start, comp.t_finish,
-                            resource=res, **attrs)
-            resps = []
-            for i, r in enumerate(comp.requests):
-                admitted = (True if comp.admit_mask is None
-                            else bool(comp.admit_mask[i]))
-                telemetry = dict(comp.extras) if comp.extras else {}
-                if comp.per_request is not None:
-                    telemetry.update(comp.per_request[i])
-                resp = InferResponse(
-                    rid=r.rid, output=comp.outputs[i], admitted=admitted,
-                    path=comp.path, arrival_s=float(r.arrival_s),
-                    t_start=comp.t_start, t_finish=comp.t_finish,
-                    batch_size=comp.size,
-                    energy_j=j_total / max(comp.size, 1),
-                    decision=decisions.get(r.rid),
-                    label=getattr(r, "label", None),
-                    telemetry=telemetry)
-                ctx.lat_window.append(resp.latency_s)
-                out.append(resp)
-                resps.append(resp)
-                self.log.add(resp)
+        # minting responses, the request log and on_completion
+        with tracer.scope("server.absorb"):
+            for comp in completions:
+                dt = comp.t_finish - comp.t_start
+                ctx.busy_s += dt
+                j_total = ctx.energy_model.p_active * dt
                 if tracer.enabled:
-                    root = self._roots.pop(r.rid, None)
-                    if root is not None:
-                        if comp.t_start > resp.arrival_s:
-                            tracer.span("queue.wait", resp.arrival_s,
-                                        comp.t_start, parent=root)
-                        tracer.end(root, comp.t_finish, path=comp.path,
-                                   admitted=admitted)
-                if ctx.metrics.enabled:
-                    self._observe_response(resp, ctx)
-            for mw in self.middleware:
-                mw.on_completion(comp, resps, ctx)
+                    # service occupancy on the engine's line: one slice per
+                    # completion, on a per-(replica, path) resource track
+                    attrs = {"batch": comp.size}
+                    flush = comp.extras.get("flush") if comp.extras else None
+                    if flush:
+                        attrs["flush"] = flush
+                    res = (f"{self.name}:{comp.path}" if self.name
+                           else comp.path)
+                    tracer.span("execute", comp.t_start, comp.t_finish,
+                                resource=res, **attrs)
+                resps = []
+                for i, r in enumerate(comp.requests):
+                    admitted = (True if comp.admit_mask is None
+                                else bool(comp.admit_mask[i]))
+                    telemetry = dict(comp.extras) if comp.extras else {}
+                    if comp.per_request is not None:
+                        telemetry.update(comp.per_request[i])
+                    resp = InferResponse(
+                        rid=r.rid, output=comp.outputs[i], admitted=admitted,
+                        path=comp.path, arrival_s=float(r.arrival_s),
+                        t_start=comp.t_start, t_finish=comp.t_finish,
+                        batch_size=comp.size,
+                        energy_j=j_total / max(comp.size, 1),
+                        decision=decisions.get(r.rid),
+                        label=getattr(r, "label", None),
+                        telemetry=telemetry)
+                    ctx.lat_window.append(resp.latency_s)
+                    out.append(resp)
+                    resps.append(resp)
+                    self.log.add(resp)
+                    if tracer.enabled:
+                        root = self._roots.pop(r.rid, None)
+                        if root is not None:
+                            if comp.t_start > resp.arrival_s:
+                                tracer.span("queue.wait", resp.arrival_s,
+                                            comp.t_start, parent=root)
+                            tracer.end(root, comp.t_finish, path=comp.path,
+                                       admitted=admitted)
+                    if ctx.metrics.enabled:
+                        self._observe_response(resp, ctx)
+                for mw in self.middleware:
+                    mw.on_completion(comp, resps, ctx)
 
     # -- signals ------------------------------------------------------------
     def pressure(self, now: float) -> float:

@@ -58,6 +58,7 @@ from repro.core.controller import AdmissionController, DraftDepthController
 from repro.models import transformer as tfm
 from repro.serving import sampling
 from repro.serving.sampling import SamplingParams
+from repro.telemetry.trace import NULL_TRACER
 
 
 @dataclass
@@ -725,9 +726,9 @@ class ContinuousBatchingEngine:
         return queue
 
     # -- serving ------------------------------------------------------------
-    def start_session(self, prompt_len: int | None = None
-                      ) -> "DecodeSession":
-        return DecodeSession(self, prompt_len=prompt_len)
+    def start_session(self, prompt_len: int | None = None,
+                      tracer=NULL_TRACER) -> "DecodeSession":
+        return DecodeSession(self, prompt_len=prompt_len, tracer=tracer)
 
     def serve(self, requests: list[GenRequest], *,
               prompt_len: int | None = None,
@@ -899,12 +900,18 @@ class DecodeSession:
     interleave with decoding); ``advance`` refills free slots with one
     bucketed prefill, runs one fused ``sync_every``-step window, and
     returns the requests that completed in that window.  All decode
-    state between windows lives on device."""
+    state between windows lives on device.
+
+    ``tracer`` scopes the host work of each ``advance``: ``sched.*``
+    for the scheduler's own (refill, seating, table upload, harvest)
+    and ``step.*`` for the jitted calls from their operand uploads to
+    their results on the host (``step.prefill``, ``step.window``)."""
 
     def __init__(self, engine: ContinuousBatchingEngine,
-                 prompt_len: int | None = None):
+                 prompt_len: int | None = None, tracer=NULL_TRACER):
         self.engine = engine
         self.prompt_len = prompt_len
+        self.tracer = tracer
         B = engine.n_slots
         self.queue: list[GenRequest] = []
         self.slots: list[GenRequest | None] = [None] * B
@@ -1103,43 +1110,47 @@ class DecodeSession:
         if eng.paged:
             self._refill_paged(free, take)
             return
-        reqs = [self.queue.pop(0) for _ in range(take)]
-        # a fixed prompt_len pins ONE prefill shape (compile-once);
-        # without it each wave pads to its own longest prompt —
-        # bucketed to a power of two so the per-(nb, plen) jit cache
-        # stays logarithmic — and a long prompt arriving mid-stream
-        # is never silently truncated to an earlier wave's length
-        plen = self.prompt_len or min(
-            _bucket(max(max(len(r.prompt) for r in reqs), 1)),
-            eng.max_seq - 1)
-        nb = _bucket(take)
-        toks = np.zeros((nb, plen), np.int32)
-        slot_idx = np.full((nb,), B, np.int32)   # OOB pad rows: dropped
-        rem_new = np.ones((nb,), np.int32)
-        eos_new = np.full((nb,), -1, np.int32)
-        skey_new, temp_new, topk_new, topp_new = \
-            self._sampling_rows(reqs, nb)
-        for j, r in enumerate(reqs):
-            p = np.asarray(r.prompt[:plen], np.int32)
-            toks[j, :len(p)] = p
-            slot_idx[j] = free[j]
-            rem_new[j] = max(r.max_new - 1, 1)
-            if r.eos_id is not None:
-                eos_new[j] = int(r.eos_id)
-        fn = eng._prefill_bucket(nb, plen)
-        t0 = time.perf_counter()
-        (self._pool, first, self._cur_tok, self._pos, self._active,
-         self._remaining, self._eos) = fn(
-            eng.params, jnp.asarray(toks), self._pool,
-            jnp.asarray(slot_idx), self._cur_tok, self._pos,
-            self._active, self._remaining, jnp.asarray(rem_new),
-            self._eos, jnp.asarray(eos_new), jnp.asarray(skey_new),
-            jnp.asarray(temp_new), jnp.asarray(topk_new),
-            jnp.asarray(topp_new))
-        first_h = np.asarray(jax.block_until_ready(first))
-        self.device_s += time.perf_counter() - t0
+        tracer = self.tracer
+        with tracer.scope("sched.refill"):
+            reqs = [self.queue.pop(0) for _ in range(take)]
+            # a fixed prompt_len pins ONE prefill shape (compile-once);
+            # without it each wave pads to its own longest prompt —
+            # bucketed to a power of two so the per-(nb, plen) jit cache
+            # stays logarithmic — and a long prompt arriving mid-stream
+            # is never silently truncated to an earlier wave's length
+            plen = self.prompt_len or min(
+                _bucket(max(max(len(r.prompt) for r in reqs), 1)),
+                eng.max_seq - 1)
+            nb = _bucket(take)
+            toks = np.zeros((nb, plen), np.int32)
+            slot_idx = np.full((nb,), B, np.int32)   # OOB pad rows: dropped
+            rem_new = np.ones((nb,), np.int32)
+            eos_new = np.full((nb,), -1, np.int32)
+            skey_new, temp_new, topk_new, topp_new = \
+                self._sampling_rows(reqs, nb)
+            for j, r in enumerate(reqs):
+                p = np.asarray(r.prompt[:plen], np.int32)
+                toks[j, :len(p)] = p
+                slot_idx[j] = free[j]
+                rem_new[j] = max(r.max_new - 1, 1)
+                if r.eos_id is not None:
+                    eos_new[j] = int(r.eos_id)
+            fn = eng._prefill_bucket(nb, plen)
+        with tracer.scope("step.prefill", nb=nb, plen=plen):
+            t0 = time.perf_counter()
+            (self._pool, first, self._cur_tok, self._pos, self._active,
+             self._remaining, self._eos) = fn(
+                eng.params, jnp.asarray(toks), self._pool,
+                jnp.asarray(slot_idx), self._cur_tok, self._pos,
+                self._active, self._remaining, jnp.asarray(rem_new),
+                self._eos, jnp.asarray(eos_new), jnp.asarray(skey_new),
+                jnp.asarray(temp_new), jnp.asarray(topk_new),
+                jnp.asarray(topp_new))
+            first_h = np.asarray(jax.block_until_ready(first))
+            self.device_s += time.perf_counter() - t0
         self.prefill_calls += 1
-        self._seat_prefilled(reqs, slot_idx, first_h)
+        with tracer.scope("sched.seat"):
+            self._seat_prefilled(reqs, slot_idx, first_h)
 
     def _seat_prefilled(self, reqs, slots_for, first_h, *,
                         on_prefill_eos=None) -> None:
@@ -1189,113 +1200,133 @@ class DecodeSession:
         B = eng.n_slots
         bs = eng.cfg.kv_block_size
         allocatable = eng.pool_blocks - 1           # block 0 = trash
-        wave: list[GenRequest] = []
-        needs: list[int] = []
-        plen_wave = self.prompt_len or 0
-        for r in self.queue[:take]:
-            solo_plen = self.prompt_len or min(
-                _bucket(max(len(r.prompt), 1)), eng.max_seq - 1)
-            solo_need = blocks_for_request(solo_plen, r.max_new,
-                                           eng.max_seq, bs)
-            if solo_need > allocatable:
-                raise ValueError(
-                    f"request rid={r.rid} needs {solo_need} KV blocks "
-                    f"(prompt {solo_plen} + max_new {r.max_new} rows "
-                    f"at block_size {bs}) but the pool has only "
-                    f"{allocatable} allocatable blocks — it can never "
-                    f"be served; raise kv_pool_blocks or shrink the "
-                    f"request budget")
-            new_plen = max(plen_wave, solo_plen)
-            # a longer prompt re-pads the whole wave: re-budget every
-            # member at the grown plen before committing to it
-            new_needs = [blocks_for_request(new_plen, x.max_new,
-                                            eng.max_seq, bs)
-                         for x in wave] + [
-                blocks_for_request(new_plen, r.max_new, eng.max_seq,
-                                   bs)]
-            if sum(new_needs) > len(self._free_blocks):
-                break                    # pool exhausted: queue waits
-            wave.append(r)
-            needs = new_needs
-            plen_wave = new_plen
-        if not wave:
-            return
-        plen = plen_wave
-        assigned = [[self._free_blocks.pop() for _ in range(n)]
-                    for n in needs]
-        reqs = [self.queue.pop(0) for _ in wave]
-        nb = _bucket(len(reqs))
-        mb = eng.blocks_per_slot
-        toks = np.zeros((nb, plen), np.int32)
-        slot_idx = np.full((nb,), B, np.int32)       # OOB pad: dropped
-        # pad rows' table entries are OOB too, so their kv-scatter rows
-        # are dropped; real rows are trash-padded past their budget
-        table_rows = np.full((nb, mb), eng.pool_blocks, np.int32)
-        rem_new = np.ones((nb,), np.int32)
-        eos_new = np.full((nb,), -1, np.int32)
-        skey_new, temp_new, topk_new, topp_new = \
-            self._sampling_rows(reqs, nb)
-        for j, r in enumerate(reqs):
-            p = np.asarray(r.prompt[:plen], np.int32)
-            toks[j, :len(p)] = p
-            slot_idx[j] = free[j]
-            row = np.zeros((mb,), np.int32)
-            row[:len(assigned[j])] = assigned[j]
-            table_rows[j] = row
-            rem_new[j] = max(r.max_new - 1, 1)
-            if r.eos_id is not None:
-                eos_new[j] = int(r.eos_id)
-        self.blocks_allocated += sum(len(a) for a in assigned)
-        self.peak_blocks_in_use = max(
-            self.peak_blocks_in_use,
-            allocatable - len(self._free_blocks))
-        fn = eng._prefill_bucket_paged(nb, plen)
-        t0 = time.perf_counter()
-        (self._pool, first, self._cur_tok, self._pos, self._active,
-         self._remaining, self._eos) = fn(
-            eng.params, jnp.asarray(toks), self._pool,
-            jnp.asarray(slot_idx), jnp.asarray(table_rows),
-            self._cur_tok, self._pos, self._active, self._remaining,
-            jnp.asarray(rem_new), self._eos, jnp.asarray(eos_new),
-            jnp.asarray(skey_new), jnp.asarray(temp_new),
-            jnp.asarray(topk_new), jnp.asarray(topp_new))
-        first_h = np.asarray(jax.block_until_ready(first))
-        self.device_s += time.perf_counter() - t0
+        tracer = self.tracer
+        with tracer.scope("sched.refill"):
+            wave: list[GenRequest] = []
+            needs: list[int] = []
+            plen_wave = self.prompt_len or 0
+            for r in self.queue[:take]:
+                solo_plen = self.prompt_len or min(
+                    _bucket(max(len(r.prompt), 1)), eng.max_seq - 1)
+                solo_need = blocks_for_request(solo_plen, r.max_new,
+                                               eng.max_seq, bs)
+                if solo_need > allocatable:
+                    raise ValueError(
+                        f"request rid={r.rid} needs {solo_need} KV blocks "
+                        f"(prompt {solo_plen} + max_new {r.max_new} rows "
+                        f"at block_size {bs}) but the pool has only "
+                        f"{allocatable} allocatable blocks — it can never "
+                        f"be served; raise kv_pool_blocks or shrink the "
+                        f"request budget")
+                new_plen = max(plen_wave, solo_plen)
+                # a longer prompt re-pads the whole wave: re-budget every
+                # member at the grown plen before committing to it
+                new_needs = [blocks_for_request(new_plen, x.max_new,
+                                                eng.max_seq, bs)
+                             for x in wave] + [
+                    blocks_for_request(new_plen, r.max_new, eng.max_seq,
+                                       bs)]
+                if sum(new_needs) > len(self._free_blocks):
+                    break                    # pool exhausted: queue waits
+                wave.append(r)
+                needs = new_needs
+                plen_wave = new_plen
+            if not wave:
+                return
+            plen = plen_wave
+            assigned = [[self._free_blocks.pop() for _ in range(n)]
+                        for n in needs]
+            reqs = [self.queue.pop(0) for _ in wave]
+            nb = _bucket(len(reqs))
+            mb = eng.blocks_per_slot
+            toks = np.zeros((nb, plen), np.int32)
+            slot_idx = np.full((nb,), B, np.int32)       # OOB pad: dropped
+            # pad rows' table entries are OOB too, so their kv-scatter rows
+            # are dropped; real rows are trash-padded past their budget
+            table_rows = np.full((nb, mb), eng.pool_blocks, np.int32)
+            rem_new = np.ones((nb,), np.int32)
+            eos_new = np.full((nb,), -1, np.int32)
+            skey_new, temp_new, topk_new, topp_new = \
+                self._sampling_rows(reqs, nb)
+            for j, r in enumerate(reqs):
+                p = np.asarray(r.prompt[:plen], np.int32)
+                toks[j, :len(p)] = p
+                slot_idx[j] = free[j]
+                row = np.zeros((mb,), np.int32)
+                row[:len(assigned[j])] = assigned[j]
+                table_rows[j] = row
+                rem_new[j] = max(r.max_new - 1, 1)
+                if r.eos_id is not None:
+                    eos_new[j] = int(r.eos_id)
+            self.blocks_allocated += sum(len(a) for a in assigned)
+            self.peak_blocks_in_use = max(
+                self.peak_blocks_in_use,
+                allocatable - len(self._free_blocks))
+            fn = eng._prefill_bucket_paged(nb, plen)
+        with tracer.scope("step.prefill", nb=nb, plen=plen):
+            t0 = time.perf_counter()
+            (self._pool, first, self._cur_tok, self._pos, self._active,
+             self._remaining, self._eos) = fn(
+                eng.params, jnp.asarray(toks), self._pool,
+                jnp.asarray(slot_idx), jnp.asarray(table_rows),
+                self._cur_tok, self._pos, self._active, self._remaining,
+                jnp.asarray(rem_new), self._eos, jnp.asarray(eos_new),
+                jnp.asarray(skey_new), jnp.asarray(temp_new),
+                jnp.asarray(topk_new), jnp.asarray(topp_new))
+            first_h = np.asarray(jax.block_until_ready(first))
+            self.device_s += time.perf_counter() - t0
         self.prefill_calls += 1
-        for j in range(len(reqs)):
-            self._table_h[free[j]] = table_rows[j]
-            self._slot_blocks[free[j]] = assigned[j]
-        self._seat_prefilled(reqs, free, first_h,
-                             on_prefill_eos=self._free_slot_blocks)
+        with tracer.scope("sched.seat"):
+            for j in range(len(reqs)):
+                self._table_h[free[j]] = table_rows[j]
+                self._slot_blocks[free[j]] = assigned[j]
+            self._seat_prefilled(reqs, free, first_h,
+                                 on_prefill_eos=self._free_slot_blocks)
 
     # -- advance ------------------------------------------------------------
     def advance(self) -> list[GenRequest]:
         """Refill free slots, run one fused k-step window, harvest.
         Returns the requests COMPLETED by this window."""
         eng = self.engine
+        tracer = self.tracer
+        with tracer.scope("sched.advance"):
+            if self._insert_q:
+                with tracer.scope("sched.inserts"):
+                    self._drain_inserts()
+            self._refill()
+            done_at_prefill, self._prefill_done = self._prefill_done, []
+            if not self._active_host.any():
+                return done_at_prefill
+            if eng.paged and self._table_dirty:
+                # retired slots' rows now point at the trash block; the
+                # window must never write a freed (possibly
+                # reallocated) block, so the mirror is applied BEFORE
+                # every window
+                with tracer.scope("sched.table"):
+                    self._pool = self._pool._replace(
+                        block_table=jnp.asarray(self._table_h))
+                self._table_dirty = False
+            if eng.draft_depth > 0:
+                self.last_depth = eng.current_depth()
+            with tracer.scope("step.window"):
+                t0 = time.perf_counter()
+                (self._pool, self._cur_tok, self._pos, self._active,
+                 self._remaining, toks, emitted) = eng._step_k(
+                    *self._window_args())
+                jax.block_until_ready(toks)
+                self.device_s += time.perf_counter() - t0
+            with tracer.scope("sched.harvest"):
+                completed = self._harvest(toks, emitted)
+        return done_at_prefill + completed
+
+    def _harvest(self, toks, emitted) -> list[GenRequest]:
+        """Pull one window's tokens to the host, update the counters,
+        extend each seated request and retire the finished ones (their
+        slots and blocks freed).  Returns the requests completed."""
+        eng = self.engine
         B = eng.n_slots
-        self._drain_inserts()
-        self._refill()
-        done_at_prefill, self._prefill_done = self._prefill_done, []
-        if not self._active_host.any():
-            return done_at_prefill
-        if eng.paged and self._table_dirty:
-            # retired slots' rows now point at the trash block; the
-            # window must never write a freed (possibly reallocated)
-            # block, so the mirror is applied BEFORE every window
-            self._pool = self._pool._replace(
-                block_table=jnp.asarray(self._table_h))
-            self._table_dirty = False
         spec = eng.draft_depth > 0
-        if spec:
-            depth = eng.current_depth()
-            self.last_depth = depth
-        t0 = time.perf_counter()
-        (self._pool, self._cur_tok, self._pos, self._active,
-         self._remaining, toks, emitted) = eng._step_k(
-            *self._window_args())
-        jax.block_until_ready(toks)
-        self.device_s += time.perf_counter() - t0
+        depth = self.last_depth
         # ONE host sync per window: token/emission pulls — [k,B], or
         # [k,D+1,B] for the speculative macro-step window
         toks_h = np.asarray(toks)
@@ -1322,7 +1353,7 @@ class DecodeSession:
         else:
             self.decode_steps += int(emit_h.any(axis=1).sum())
             self.occupied_slot_steps += int(emit_h.sum())
-        completed: list[GenRequest] = list(done_at_prefill)
+        completed: list[GenRequest] = []
         for s in range(B):
             r = self.slots[s]
             if r is None:

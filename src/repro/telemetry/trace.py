@@ -9,7 +9,7 @@ virtual-time simulators (`FleetSimulator` / `DisaggSimulator`), whose
 "now" is a scheduling variable, not a reading of any clock.  When a
 timestamp is omitted the tracer falls back to its injected clock.
 
-Three recording shapes cover every seam in the stack:
+Four recording shapes cover every seam in the stack:
 
 - ``span(name, t_start, t_end)`` — a completed interval (most sim spans
   are known only once the service line has reserved them).
@@ -17,6 +17,13 @@ Three recording shapes cover every seam in the stack:
   path (root request spans open at arrival, close at absorb).
 - ``event(name, t)`` — an instant (router decisions, autoscaler actions,
   XLA compile markers).
+- ``with scope(name):`` — host work at a layer boundary of the serving
+  loop (``server.*``, ``sched.*``, ``step.*``).  It always enters a
+  ``jax.profiler.TraceAnnotation`` of the bare name, so a profiler
+  session records it on the host plane, on the clock of the device
+  planes; an enabled tracer also records it in memory on its own
+  clock, parented to the enclosing scope.  Scopes sit in host code
+  only, never inside a jitted function.
 
 Spans carry an optional ``resource`` — the serialized thing they occupy
 (a service line, a transfer link, a decode slot).  Spans that share a
@@ -30,6 +37,7 @@ construction behind ``tracer.enabled``.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 from dataclasses import dataclass, field
@@ -83,6 +91,23 @@ class VirtualClock:
     def advance(self, dt: float) -> float:
         self.t += float(dt)
         return self.t
+
+
+# ---------------------------------------------------------------------------
+# profiler annotations
+
+_TraceAnnotation: Any = None
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, imported on first use so
+    this module imports without JAX.  With no profiler session running
+    it costs about a microsecond."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
 
 
 # ---------------------------------------------------------------------------
@@ -141,6 +166,7 @@ class Tracer:
         self.clock = clock if clock is not None else WallClock()
         self.spans: List[Span] = []
         self._next_id = 1
+        self._scopes: List[Span] = []      # open scopes, innermost last
 
     # -- recording ----------------------------------------------------------
 
@@ -212,6 +238,22 @@ class Tracer:
         now = self._now(t)
         return self.span(name, now, now, parent=parent, resource=resource, **attrs)
 
+    @contextlib.contextmanager
+    def scope(self, name: str, **attrs: Any):
+        """Time the enclosed host work as ``name``: a profiler
+        annotation of the bare name, and a span on this tracer's clock
+        whose parent is the enclosing scope.  Attributes are recorded
+        in memory only; pass values the host already holds."""
+        parent = self._scopes[-1] if self._scopes else None
+        s = self.begin(name, parent=parent, **attrs)
+        self._scopes.append(s)
+        try:
+            with _annotation(name):
+                yield s
+        finally:
+            self._scopes.pop()
+            self.end(s)
+
     # -- introspection ------------------------------------------------------
 
     def open_spans(self) -> List[Span]:
@@ -243,7 +285,8 @@ class NullTracer(Tracer):
     """No-op recorder: the default everywhere; records nothing.
 
     Instrumented call sites may call any recording method unguarded —
-    every method returns immediately.  Sites that would *construct*
+    every method returns immediately, and ``scope`` keeps only its
+    profiler annotation.  Sites that would *construct*
     expensive attributes should still guard on ``tracer.enabled``.
     """
 
@@ -267,6 +310,11 @@ class NullTracer(Tracer):
 
     def event(self, name, t=None, *, parent=None, resource=None, **attrs):  # type: ignore[override]
         return NullTracer._NULL_SPAN
+
+    def scope(self, name, **attrs):  # type: ignore[override]
+        # the profiler annotation alone: a profiled run still sees the
+        # serving loop's layers with no tracer passed in
+        return _annotation(name)
 
 
 NullTracer._NULL_SPAN = Span(name="null", t_start=0.0, t_end=0.0, span_id=0)

@@ -12,6 +12,7 @@ pytest-xdist worker collects the same tests and only the worker that
 runs this file loads the TPU library.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,39 +55,64 @@ def one_chip(topo):
     cc.reset_cache()
 
 
-def _compile(one_chip, fn, *shapes):
+def _compile(one_chip, fn, *shapes) -> str:
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
     text = jax.jit(functools.partial(fn, interpret=False)).lower(
         *args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
+
+
+MB = MAX_SEQ // BS
+PAGED = (((B, H, HD), jnp.bfloat16),
+         ((1 + B * MB, BS, K, HD), jnp.bfloat16),
+         ((1 + B * MB, BS, K, HD), jnp.bfloat16),
+         ((B, MB), jnp.int32), ((B, MAX_SEQ), jnp.int32),
+         ((B,), jnp.int32))
+DECODE = (((B, H, HD), jnp.bfloat16),
+          ((B, K, MAX_SEQ, HD), jnp.bfloat16),
+          ((B, K, MAX_SEQ, HD), jnp.bfloat16),
+          ((B, MAX_SEQ), jnp.int32), ((B,), jnp.int32))
+
+
+def _flash(seq: int) -> tuple:
+    return (((B, H, seq, HD), jnp.bfloat16),
+            ((B, K, seq, HD), jnp.bfloat16),
+            ((B, K, seq, HD), jnp.bfloat16))
 
 
 def test_paged_decode_attention_compiles(one_chip):
-    mb = MAX_SEQ // BS
-    nb = 1 + B * mb
-    _compile(one_chip, dak.paged_decode_attention,
-             ((B, H, HD), jnp.bfloat16),
-             ((nb, BS, K, HD), jnp.bfloat16),
-             ((nb, BS, K, HD), jnp.bfloat16),
-             ((B, mb), jnp.int32), ((B, MAX_SEQ), jnp.int32),
-             ((B,), jnp.int32))
+    _compile(one_chip, dak.paged_decode_attention, *PAGED)
 
 
 def test_decode_attention_compiles(one_chip):
-    _compile(one_chip, dak.decode_attention,
-             ((B, H, HD), jnp.bfloat16),
-             ((B, K, MAX_SEQ, HD), jnp.bfloat16),
-             ((B, K, MAX_SEQ, HD), jnp.bfloat16),
-             ((B, MAX_SEQ), jnp.int32), ((B,), jnp.int32))
+    _compile(one_chip, dak.decode_attention, *DECODE)
 
 
 @pytest.mark.parametrize("seq", [128, 16])
 def test_flash_attention_compiles(one_chip, seq):
-    _compile(one_chip, fak.flash_attention,
-             ((B, H, seq, HD), jnp.bfloat16),
-             ((B, K, seq, HD), jnp.bfloat16),
-             ((B, K, seq, HD), jnp.bfloat16))
+    _compile(one_chip, fak.flash_attention, *_flash(seq))
+
+
+@pytest.mark.parametrize("name, fn, shapes", [
+    ("paged_decode_attention", dak.paged_decode_attention, PAGED),
+    ("decode_attention", dak.decode_attention, DECODE),
+    ("flash_attention", fak.flash_attention, _flash(128))])
+def test_kernel_keeps_its_name_in_any_caller(one_chip, name, fn, shapes):
+    """The device trace names a kernel's operation after its
+    ``pallas_call``, and the benchmark's roofline readers match that
+    name: a caller of another name, without the wrapper's own jit,
+    must not rename it."""
+    inner = fn.__wrapped__
+
+    def renamed_caller(*args, interpret):
+        return inner(*args, interpret=interpret)
+
+    text = _compile(one_chip, renamed_caller, *shapes)
+    ops = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                     r"\"tpu_custom_call\"", text)
+    assert ops and all(op.rsplit(".", 1)[0] == name for op in ops), ops
 
 
 @pytest.mark.parametrize("vocab", [CFG.vocab, 2])

@@ -75,6 +75,41 @@ def test_event_is_instant_and_null_tracer_noops():
     assert NULL_TRACER.spans == []
 
 
+def test_scope_nests_on_the_tracers_clock():
+    clk = VirtualClock(1.0)
+    tr = Tracer(clock=clk)
+    with tr.scope("server.push") as push:
+        clk.advance(0.5)
+        with tr.scope("sched.advance") as adv:
+            with tr.scope("step.window", active=3) as win:
+                clk.advance(2.0)
+        with tr.scope("server.absorb") as absorb:
+            clk.advance(0.25)
+    assert push.parent_id is None
+    assert adv.parent_id == push.span_id
+    assert win.parent_id == adv.span_id and win.attrs == {"active": 3}
+    assert absorb.parent_id == push.span_id
+    assert (push.t_start, push.t_end) == (1.0, 3.75)
+    assert (win.t_start, win.t_end) == (1.5, 3.5)
+    assert (absorb.t_start, absorb.t_end) == (3.5, 3.75)
+    assert not tr.open_spans() and validate_trace(tr.spans) == []
+    # a scope left by an exception is closed, and the next one is a
+    # root again
+    with pytest.raises(RuntimeError):
+        with tr.scope("sched.refill"):
+            raise RuntimeError("wave refused")
+    with tr.scope("server.poke") as poke:
+        pass
+    assert tr.find("sched.refill")[0].closed and poke.parent_id is None
+
+
+def test_null_tracer_scope_records_nothing():
+    with NULL_TRACER.scope("server.push"):
+        with NULL_TRACER.scope("step.window", active=3):
+            pass
+    assert NULL_TRACER.spans == []
+
+
 # ---------------------------------------------------------------------------
 # validation
 
