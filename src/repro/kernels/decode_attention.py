@@ -11,12 +11,14 @@ purely HBM-bandwidth bound, which is exactly what the roofline says.
 Two paged entry points serve the vLLM-style shared block pool:
 
   - :func:`paged_decode_attention` — the TABLE-NATIVE kernel.  The
-    slot's ``block_table`` row is scalar-prefetched
-    (``pltpu.PrefetchScalarGridSpec``) and every grid step's HBM→VMEM
-    DMA is redirected through it by the BlockSpec index_map, so the
-    kernel streams ``[block_size, K, hd]`` blocks straight out of the
-    shared pool.  No gather, no contiguous copy — the pool's K/V bytes
-    cross HBM exactly once per decode step.
+    slot's ``block_table`` row and the layer index are
+    scalar-prefetched (``pltpu.PrefetchScalarGridSpec``) and every
+    grid step's HBM→VMEM DMA is redirected through them by the
+    BlockSpec index_map, so the kernel streams ``[block_size, K, hdp]``
+    blocks of one layer straight out of the stacked pool
+    ``[L, NB, bs, K, hdp]`` (rows zero-padded from hd to hdp lanes).  No gather, no contiguous copy, no
+    per-layer slice.  The grid visits every entry of the slot's table
+    row, so unmapped entries re-read trash block 0.
   - :func:`paged_decode_attention_shim` — the materialised-gather
     shim kept as the parity oracle: one XLA gather rebuilds the
     contiguous [B, K, S, hd] view, then the contiguous kernel runs on
@@ -165,19 +167,29 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, H, hd)
 
 
+def stacked_pool(pool: jax.Array) -> jax.Array:
+    """A pool as a stack of per-layer pools [L, NB, bs, K, hd]: one
+    pool [NB, bs, K, hd] is the stack of one layer (a free reshape)."""
+    return pool[None] if pool.ndim == 4 else pool
+
+
 def gather_block_views(k_pool: jax.Array, v_pool: jax.Array,
-                       block_table: jax.Array,
-                       n_ctx: int) -> tuple[jax.Array, jax.Array]:
+                       block_table: jax.Array, n_ctx: int, layer=0, *,
+                       head_dim: int | None = None
+                       ) -> tuple[jax.Array, jax.Array]:
     """Gather each slot's mapped blocks into the contiguous logical
-    view: pool [NB, bs, K, hd] + table [B, MB] -> k/v
-    [B, n_ctx, K, hd] (BSHD, the gather's natural layout — the decode
-    kernels transpose to their BHSD at the call site).  The ONE
-    implementation of the block-table gather — the Pallas shim,
-    the jnp ops dispatch AND the model layer's ``attn.paged_gather``
-    all go through it, so table semantics can never diverge between
-    paths."""
+    view: pool [NB, bs, K, hdp] (or layer ``layer`` of a stacked pool
+    [L, NB, bs, K, hdp]) + table [B, MB] -> k/v [B, n_ctx, K, hd],
+    the first ``head_dim`` lanes of each row (all hdp when ``None``;
+    a pool's head axis may be padded past the model's head size)
+    (BSHD, the gather's natural layout — the decode kernels transpose
+    to their BHSD at the call site).  The ONE implementation of the
+    block-table gather — the Pallas shim, the jnp ops dispatch AND the
+    model layer's ``attn.paged_gather`` all go through it, so table
+    semantics can never diverge between paths."""
+    k_pool, v_pool = stacked_pool(k_pool), stacked_pool(v_pool)
     B = block_table.shape[0]
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     if n_ctx % bs != 0:
         raise ValueError(
             f"paged gather: logical extent n_ctx={n_ctx} is not a "
@@ -193,26 +205,29 @@ def gather_block_views(k_pool: jax.Array, v_pool: jax.Array,
             f"{block_table.shape[1]} per slot (table "
             f"{tuple(block_table.shape)})")
     tb = block_table[:, :n_blocks]                      # [B, MB]
-    k = k_pool[tb].reshape(B, n_ctx, *k_pool.shape[2:])
-    v = v_pool[tb].reshape(B, n_ctx, *v_pool.shape[2:])
-    return k, v
+    k = k_pool[layer, tb, ..., :head_dim]
+    v = v_pool[layer, tb, ..., :head_dim]
+    return (k.reshape(B, n_ctx, *k.shape[3:]),
+            v.reshape(B, n_ctx, *v.shape[3:]))
 
 
 # ---------------------------------------------------------------------------
 # paged flash-decode — TABLE-NATIVE kernel (scalar-prefetched DMA)
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(tbl_ref, cur_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale: float, window: int):
+def _paged_kernel(tbl_ref, cur_ref, layer_ref, q_ref, k_ref, v_ref,
+                  pos_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float,
+                  window: int):
     """One grid step = one mapped pool block of the slot, all heads.
 
-    ``tbl_ref`` is the scalar-prefetched block table — the kernel body
-    never touches it; the BlockSpec index_maps already used it to
-    redirect this step's HBM→VMEM DMA, so ``k_ref``/``v_ref`` hold the
-    [bs, K, hd] rows of pool block ``tbl[b, ki]``.  The math is
+    ``tbl_ref`` (the block table) and ``layer_ref`` (the layer index)
+    are scalar-prefetched — the kernel body never touches them; the
+    BlockSpec index_maps already used them to redirect this step's
+    HBM→VMEM DMA, so ``k_ref``/``v_ref`` hold the [bs, K, hd] rows of
+    pool block ``tbl[b, ki]`` of that layer.  The math is
     ``_decode_body``, the contiguous kernel's own, which is what makes
     the shim byte-identical at k_blk == bs."""
-    del tbl_ref
+    del tbl_ref, layer_ref
     _decode_body(q_ref, k_ref, v_ref, pos_ref, o_ref, m_ref, l_ref,
                  acc_ref, cur=cur_ref[pl.program_id(0)], scale=scale,
                  window=window, heads_minor=True)
@@ -221,33 +236,39 @@ def _paged_kernel(tbl_ref, cur_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, block_table: jax.Array,
-                           kv_pos: jax.Array, cur_pos: jax.Array, *,
-                           window: int = 0,
+                           kv_pos: jax.Array, cur_pos: jax.Array,
+                           layer=0, *, window: int = 0,
                            interpret: bool | None = None) -> jax.Array:
     """Flash-decode over a paged block pool — TABLE-NATIVE.
 
-    q [B,H,hd]; k_pool/v_pool [NB, bs, K, hd] (one physical pool);
+    q [B,H,hd]; k_pool/v_pool [L, NB, bs, K, hdp], the per-layer pools
+    of a layer stack, read at layer ``layer`` (a scalar, traced or
+    not), or one pool [NB, bs, K, hdp] (the one-layer stack, layer 0),
+    whose rows may be padded past hd with zeros (hdp >= hd: the kernel
+    pads q to match and drops the pad lanes of its output);
     block_table [B, MB] maps each slot's logical block to a pool
     block; kv_pos [B, MB*bs] per-slot absolute positions (-1 = empty);
     cur_pos [B] -> [B,H,hd].
 
-    The block table and ``cur_pos`` ride in as scalar-prefetch
-    operands (``pltpu.PrefetchScalarGridSpec``): they are resident in
-    SMEM before the first grid step, and the k/v BlockSpec index_maps
-    read ``tbl[b, ki]`` to aim each step's HBM→VMEM DMA at the slot's
-    ki-th mapped pool block — all K heads of it, so the block spans
-    the pool's last two dims.  The shared pool is therefore consumed
-    IN PLACE — no materialised gather, no contiguous copy, no second
-    pass over the cache bytes.  The grid's KV chunk is the pool block
-    size (DMAs must land on pool-block boundaries; a k_blk knob would
-    either re-introduce the copy or be a lie).
+    The block table, ``cur_pos`` and the layer index ride in as
+    scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``): they
+    are resident in SMEM before the first grid step, and the k/v
+    BlockSpec index_maps read ``(layer, tbl[b, ki])`` to aim each
+    step's HBM→VMEM DMA at the slot's ki-th mapped pool block of that
+    layer — all K heads of it, so the block spans the pool's last two
+    dims.  The stacked pool is therefore consumed IN PLACE — no
+    per-layer slice, no materialised gather, no contiguous copy.  The
+    grid's KV chunk is the pool block size (DMAs must land on
+    pool-block boundaries; a k_blk knob would either re-introduce the
+    copy or be a lie).
 
     ``kv_pos`` validity masking is unchanged from the contiguous
     kernel, so trash-block rows (unmapped table entries point at
     block 0) are never attended."""
     interpret = resolve_interpret(interpret)
+    k_pool, v_pool = stacked_pool(k_pool), stacked_pool(v_pool)
     B, H, hd = q.shape
-    bs, K = k_pool.shape[1], k_pool.shape[2]
+    bs, K, hdp = k_pool.shape[2:]
     G = H // K
     C = kv_pos.shape[1]
     if C % bs != 0:
@@ -264,40 +285,45 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     scale = 1.0 / math.sqrt(hd)
 
     kernel = functools.partial(_paged_kernel, scale=scale, window=window)
+
+    def kv_block(b, ki, tbl, cur, lay):
+        return lay[0], tbl[b, ki], 0, 0, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, nk),
         in_specs=[
-            pl.BlockSpec((1, K, G, hd),
-                         lambda b, ki, tbl, cur: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, K, hd),
-                         lambda b, ki, tbl, cur: (tbl[b, ki], 0, 0, 0)),
-            pl.BlockSpec((1, bs, K, hd),
-                         lambda b, ki, tbl, cur: (tbl[b, ki], 0, 0, 0)),
-            pl.BlockSpec((1, nk, bs), lambda b, ki, tbl, cur: (b, 0, 0)),
+            pl.BlockSpec((1, K, G, hdp),
+                         lambda b, ki, tbl, cur, lay: (b, 0, 0, 0)),
+            pl.BlockSpec((None, 1, bs, K, hdp), kv_block),
+            pl.BlockSpec((None, 1, bs, K, hdp), kv_block),
+            pl.BlockSpec((1, nk, bs),
+                         lambda b, ki, tbl, cur, lay: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, K, G, hd),
-                               lambda b, ki, tbl, cur: (b, 0, 0, 0)),
-        scratch_shapes=_scratch(K, G, hd),
+        out_specs=pl.BlockSpec((1, K, G, hdp),
+                               lambda b, ki, tbl, cur, lay: (b, 0, 0, 0)),
+        scratch_shapes=_scratch(K, G, hdp),
     )
+    qp = jnp.pad(q, ((0, 0), (0, 0), (0, hdp - hd)))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, G, hdp), q.dtype),
         interpret=interpret,
         name="paged_decode_attention",
     )(block_table.astype(jnp.int32), cur_pos.astype(jnp.int32),
-      q.reshape(B, K, G, hd), k_pool, v_pool,
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      qp.reshape(B, K, G, hdp), k_pool, v_pool,
       kv_pos.astype(jnp.int32).reshape(B, nk, bs))
-    return out.reshape(B, H, hd)
+    return out[..., :hd].reshape(B, H, hd)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("window", "k_blk", "interpret"))
 def paged_decode_attention_shim(q: jax.Array, k_pool: jax.Array,
                                 v_pool: jax.Array, block_table: jax.Array,
-                                kv_pos: jax.Array, cur_pos: jax.Array, *,
-                                window: int = 0,
+                                kv_pos: jax.Array, cur_pos: jax.Array,
+                                layer=0, *, window: int = 0,
                                 k_blk: int | None = None,
                                 interpret: bool | None = None
                                 ) -> jax.Array:
@@ -312,7 +338,8 @@ def paged_decode_attention_shim(q: jax.Array, k_pool: jax.Array,
     extra pass over the cache bytes per micro-step, which is why it is
     no longer the serving path."""
     k, v = gather_block_views(k_pool, v_pool, block_table,
-                              kv_pos.shape[1])
+                              kv_pos.shape[1], layer,
+                              head_dim=q.shape[-1])
     return decode_attention(q, k.transpose(0, 2, 1, 3),
                             v.transpose(0, 2, 1, 3), kv_pos, cur_pos,
                             window=window, k_blk=k_blk,

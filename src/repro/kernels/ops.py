@@ -75,30 +75,35 @@ def decode_attention(q, k, v, kv_pos, cur_pos, *, window=0,
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_table, kv_pos,
-                           cur_pos, *, window=0, impl: str = "auto"):
-    """q [B,H,hd]; k/v pool [NB,bs,K,hd]; block_table [B,MB];
+                           cur_pos, layer=0, *, window=0,
+                           impl: str = "auto"):
+    """q [B,H,hd]; k/v pool [L,NB,bs,K,hdp] read at ``layer`` (or one
+    pool [NB,bs,K,hdp]), rows padded to hdp >= hd; block_table [B,MB];
     kv_pos [B,MB*bs]; cur_pos [B] -> [B,H,hd].
 
     The paged serving hot path: the TABLE-NATIVE flash-decode kernel —
-    the slot's block-table row is scalar-prefetched and each grid
-    step's HBM→VMEM DMA is redirected through it, so the shared pool
-    is consumed in place with no materialised gather.  ``impl="shim"``
-    forces the old gather-then-contiguous-kernel path, kept as the
-    parity oracle (byte-identical at ``k_blk == block_size``).
+    the slot's block-table row and the layer are scalar-prefetched and
+    each grid step's HBM→VMEM DMA is redirected through them, so the
+    stacked pool is consumed in place with no slice and no materialised
+    gather.  ``impl="shim"`` forces the old gather-then-contiguous-kernel
+    path, kept as the parity oracle (byte-identical at
+    ``k_blk == block_size``).
     Validity is carried entirely by ``kv_pos`` — unmapped table
     entries point at the trash block whose rows are never valid."""
     if not _use_kernel(impl, impls=_PAGED_IMPLS):
         k, v = _da.gather_block_views(k_pool, v_pool, block_table,
-                                      kv_pos.shape[1])
+                                      kv_pos.shape[1], layer,
+                                      head_dim=q.shape[-1])
         return _ref.decode_attention(q, k.transpose(0, 2, 1, 3),
                                      v.transpose(0, 2, 1, 3),
                                      kv_pos, cur_pos, window=window)
     if impl == "shim":
         return _da.paged_decode_attention_shim(
-            q, k_pool, v_pool, block_table, kv_pos, cur_pos,
-            window=window, k_blk=k_pool.shape[1])
+            q, k_pool, v_pool, block_table, kv_pos, cur_pos, layer,
+            window=window, k_blk=k_pool.shape[-3])
     return _da.paged_decode_attention(q, k_pool, v_pool, block_table,
-                                      kv_pos, cur_pos, window=window)
+                                      kv_pos, cur_pos, layer,
+                                      window=window)
 
 
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk=128, impl: str = "auto"):

@@ -325,10 +325,11 @@ def cache_write(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
 # identical to the contiguous layout (slot scatters are plain
 # ``tree_map``-free indexed writes either way):
 #
-#   k, v  [NB, bs, K, hd]   one physical pool of NB blocks of bs rows,
+#   k, v  [NB, bs, K, hdp]  one physical pool of NB blocks of bs rows,
 #                           shared by every slot (block 0 is reserved
 #                           as the trash block — writes by retired
-#                           slots land there harmlessly)
+#                           slots land there harmlessly); the head axis
+#                           is padded to hdp = pool_head_dim(hd)
 #   pos   [B, C]            per-slot LOGICAL validity/position array,
 #                           C = max_blocks_per_slot * bs (-1 = empty);
 #                           identical semantics to the contiguous pos
@@ -338,73 +339,123 @@ def cache_write(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
 # ``transformer.Cache``) maps logical block j of slot b to a physical
 # pool block; unmapped entries point at the trash block and are
 # excluded by the pos validity mask, never by the table itself.
+#
+# The functions below take one layer's cache, or, with ``layer``
+# given, the stacked cache of a layer stack (k, v [L, NB, bs, K, hdp],
+# pos [L, B, C], length [L]) and the index of the layer they act on:
+# the decode stack carries the stacked pool and writes and reads it in
+# place, so no layer slices it out or stacks it back.
+
+_LANES = 128
+
+
+def pool_head_dim(head_dim: int) -> int:
+    """Head size of a pool row: ``head_dim`` rounded up to a multiple
+    of 128 lanes.  A Pallas operand must be row-major, and a TPU lays
+    out a pool whose minor dim is not a lane multiple otherwise (a head
+    dim of 80 puts the block axis minor-most), so every program that
+    took or returned such a pool would convert both whole pools at its
+    boundary.  Padded, the default layout is row-major, in the same
+    bytes: row-major tiles pad 80 lanes to 128 anyway.  The pad lanes
+    hold zeros and add nothing: the kernel pads ``q`` with zeros and
+    the gather path drops them."""
+    return -(-head_dim // _LANES) * _LANES
+
+
+def pad_head(x: jax.Array, n: int) -> jax.Array:
+    """``x`` with its last (head) axis zero-padded to ``n`` lanes."""
+    pad = n - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 def init_paged_kv_cache(batch: int, logical_len: int, n_kv: int,
                         head_dim: int, *, n_blocks: int, block_size: int,
                         dtype=jnp.bfloat16) -> KVCache:
     """Pool-layout KVCache: ``n_blocks`` x ``block_size`` rows shared
-    by ``batch`` slots whose logical extent is ``logical_len`` rows."""
+    by ``batch`` slots whose logical extent is ``logical_len`` rows;
+    rows of ``pool_head_dim(head_dim)`` lanes."""
+    hdp = pool_head_dim(head_dim)
     return KVCache(
-        k=jnp.zeros((n_blocks, block_size, n_kv, head_dim), dtype),
-        v=jnp.zeros((n_blocks, block_size, n_kv, head_dim), dtype),
+        k=jnp.zeros((n_blocks, block_size, n_kv, hdp), dtype),
+        v=jnp.zeros((n_blocks, block_size, n_kv, hdp), dtype),
         pos=jnp.full((batch, logical_len), -1, jnp.int32),
         length=jnp.zeros((), jnp.int32))
 
 
 def paged_cache_write(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
-                      pos, block_table: jax.Array,
-                      block_size: int) -> KVCache:
+                      pos, block_table: jax.Array, block_size: int,
+                      layer=None) -> KVCache:
     """Write ONE token per slot at its own absolute position.
 
     k_new/v_new [B, 1, K, hd]; ``pos`` scalar or [B]; the physical row
-    is ``(block_table[b, pos_b // bs], pos_b % bs)``.  Slots whose
-    table row points at the trash block (retired slots still being
-    stepped inside a fused window) write there harmlessly; their pos
-    entry is per-slot and reset at the next prefill."""
+    is ``(block_table[b, pos_b // bs], pos_b % bs)`` — of layer
+    ``layer`` when the cache is stacked, one scatter into the stack.
+    Slots whose table row points at the trash block (retired slots
+    still being stepped inside a fused window) write there harmlessly;
+    their pos entry is per-slot and reset at the next prefill."""
     B = k_new.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     posv = jnp.broadcast_to(pos, (B,)) if pos.ndim == 0 else pos
     b = jnp.arange(B, dtype=jnp.int32)
     blk = block_table[b, posv // block_size]            # [B]
     off = posv % block_size
-    k = cache.k.at[blk, off].set(k_new[:, 0].astype(cache.k.dtype))
-    v = cache.v.at[blk, off].set(v_new[:, 0].astype(cache.v.dtype))
-    p = cache.pos.at[b, posv].set(posv, mode="drop")
-    return KVCache(k=k, v=v, pos=p, length=jnp.max(posv) + 1)
+    at = () if layer is None else (layer,)
+    hdp = cache.k.shape[-1]
+    k = cache.k.at[at + (blk, off)].set(
+        pad_head(k_new[:, 0], hdp).astype(cache.k.dtype))
+    v = cache.v.at[at + (blk, off)].set(
+        pad_head(v_new[:, 0], hdp).astype(cache.v.dtype))
+    p = cache.pos.at[at + (b, posv)].set(posv, mode="drop")
+    length = jnp.max(posv) + 1
+    if layer is not None:
+        length = cache.length.at[layer].set(length)
+    return KVCache(k=k, v=v, pos=p, length=length)
 
 
-def paged_gather(cache: KVCache, block_table: jax.Array) -> KVCache:
+def _layer_pos(cache: KVCache, layer) -> jax.Array:
+    """The [B, C] position rows of layer ``layer`` (or of the one
+    layer an unstacked cache holds)."""
+    return cache.pos if layer is None else cache.pos[layer]
+
+
+def paged_gather(cache: KVCache, block_table: jax.Array, layer=None,
+                 head_dim: int | None = None) -> KVCache:
     """Materialise each slot's logical [B, C, K, hd] view of the pool
     (gather over the block table).  The result is a CONTIGUOUS-layout
     KVCache, so every downstream consumer (``decode_attend``, the
     gather-shim flash-decode path) runs unchanged on it.  The serving
     hot path no longer needs this — the table-native kernel reads the
     pool in place — but the table indexing stays single-sourced in
-    ``repro.kernels.decode_attention.gather_block_views``."""
+    ``repro.kernels.decode_attention.gather_block_views``.  Rows keep
+    their first ``head_dim`` lanes (all of them when ``None``)."""
     from repro.kernels.decode_attention import gather_block_views
-    C = cache.pos.shape[1]
-    k, v = gather_block_views(cache.k, cache.v, block_table, C)
-    return KVCache(k=k, v=v, pos=cache.pos, length=cache.length)
+    kv_pos = _layer_pos(cache, layer)
+    k, v = gather_block_views(cache.k, cache.v, block_table,
+                              kv_pos.shape[1], 0 if layer is None
+                              else layer, head_dim=head_dim)
+    return KVCache(k=k, v=v, pos=kv_pos, length=cache.length)
 
 
 def paged_decode_attend(q: jax.Array, cache: KVCache,
                         block_table: jax.Array, *, pos: jax.Array,
-                        window: int = 0,
-                        scale: float | None = None) -> jax.Array:
+                        window: int = 0, scale: float | None = None,
+                        layer=None) -> jax.Array:
     """One-token attention over the slot's mapped blocks (jnp path).
 
     Validity comes from the per-slot ``pos`` array exactly as in the
     contiguous layout — unmapped blocks are never valid because their
     logical rows were never written."""
-    return decode_attend(q, paged_gather(cache, block_table), pos=pos,
-                         window=window, scale=scale)
+    kv = paged_gather(cache, block_table, layer, head_dim=q.shape[-1])
+    return decode_attend(q, kv, pos=pos, window=window, scale=scale)
 
 
 def paged_decode_attend_kernel(q: jax.Array, cache: KVCache,
                                block_table: jax.Array, *,
                                pos: jax.Array, window: int = 0,
-                               impl: str = "auto") -> jax.Array:
+                               impl: str = "auto",
+                               layer=None) -> jax.Array:
     """One-token paged attention through the block-table-aware
     ``kops.paged_decode_attention`` dispatch: the TABLE-NATIVE
     flash-decode kernel on TPU (block table scalar-prefetched, pool
@@ -415,8 +466,8 @@ def paged_decode_attend_kernel(q: jax.Array, cache: KVCache,
     pos = jnp.asarray(pos, jnp.int32)
     cur = jnp.broadcast_to(pos, (B,)) if pos.ndim == 0 else pos
     o = kops.paged_decode_attention(
-        q[:, 0], cache.k, cache.v, block_table, cache.pos, cur,
-        window=window, impl=impl)
+        q[:, 0], cache.k, cache.v, block_table, _layer_pos(cache, layer),
+        cur, 0 if layer is None else layer, window=window, impl=impl)
     return o[:, None]
 
 

@@ -222,13 +222,14 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
     if paged:
         _check_paged_supported(cfg)
         mb, logical, nb = paged_geometry(cfg, batch, max_seq)
-        per = [LayerCache(kv=attn.init_paged_kv_cache(
-                   batch, logical, cfg.n_kv_heads, cfg.head_dim,
-                   n_blocks=nb, block_size=cfg.kv_block_size,
-                   dtype=dtype))
-               for _ in range(cfg.n_layers)]
-        layers = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per)
-        return Cache(layers=layers, cross=None,
+        one = attn.init_paged_kv_cache(
+            batch, logical, cfg.n_kv_heads, cfg.head_dim, n_blocks=nb,
+            block_size=cfg.kv_block_size, dtype=dtype)
+        # made stacked in one step: stacking per-layer pools would hold
+        # two whole pools at once
+        kv = jax.tree_util.tree_map(
+            lambda x: jnp.broadcast_to(x, (cfg.n_layers, *x.shape)), one)
+        return Cache(layers=LayerCache(kv=kv), cross=None,
                      length=jnp.zeros((), jnp.int32),
                      block_table=jnp.zeros((batch, mb), jnp.int32))
     kinds = cfg.block_kinds
@@ -272,13 +273,14 @@ def _channel_mix(cfg: ModelConfig, p: dict, h: jax.Array):
 
 def _attn_mix(cfg: ModelConfig, kind: str, p: dict, x: jax.Array, *,
               mode: str, lc: LayerCache, pos, prefix_len,
-              block_table=None):
+              block_table=None, layer=None):
     """Temporal mixing for attn/local_attn. Returns (y, new LayerCache).
 
-    ``block_table`` is non-None only on the paged decode path: the
-    layer's KV leaves are then pool-layout ([NB, bs, K, hd]) and both
-    the single-token write and the attention gather go through the
-    slot's block-table row."""
+    ``block_table`` is non-None only on the paged decode path: the KV
+    leaves are then pool-layout — the whole stack's ([L, NB, bs, K,
+    hdp]) when ``layer`` is given — and both the single-token write and
+    the attention read of layer ``layer`` go through the slot's
+    block-table row."""
     window = cfg.window if kind == "local_attn" else 0
     rd = int(cfg.head_dim * cfg.rope_pct)
     # kernel dispatch: ``attn_impl="auto"`` resolves HERE, not inside
@@ -342,14 +344,14 @@ def _attn_mix(cfg: ModelConfig, kind: str, p: dict, x: jax.Array, *,
     k = nn.apply_rope(k, posv, cfg.rope_theta, rotary_dim=rd)
     if block_table is not None:
         kv = attn.paged_cache_write(lc.kv, k, v, pos, block_table,
-                                    cfg.kv_block_size)
+                                    cfg.kv_block_size, layer)
         if use_kernel:
             o = attn.paged_decode_attend_kernel(
                 q, kv, block_table, pos=pos, window=window,
-                impl=cfg.attn_impl)
+                impl=cfg.attn_impl, layer=layer)
         else:
             o = attn.paged_decode_attend(q, kv, block_table, pos=pos,
-                                         window=window)
+                                         window=window, layer=layer)
         return attn.out_proj(p, o), LayerCache(kv=kv, rec=lc.rec)
     kv = attn.cache_write(lc.kv, k, v, pos)
     if use_kernel:
@@ -389,13 +391,14 @@ def _rec_mix(cfg: ModelConfig, kind: str, p: dict, x: jax.Array, *,
 
 def apply_layer(cfg: ModelConfig, kind: str, p: dict, h: jax.Array, *,
                 mode: str, lc: LayerCache, pos=0, prefix_len=0,
-                xattn=None, cross_kv=None, block_table=None):
-    """One residual block: temporal mix + optional cross-attn + channel."""
+                xattn=None, cross_kv=None, block_table=None, layer=None):
+    """One residual block: temporal mix + optional cross-attn + channel.
+    ``layer`` indexes a stacked paged pool in ``lc`` (paged decode)."""
     hn = nn.apply_norm(cfg.norm, p["norm1"], h)
     if kind in ("attn", "local_attn"):
         y, new_lc = _attn_mix(cfg, kind, p["mix"], hn, mode=mode, lc=lc,
                               pos=pos, prefix_len=prefix_len,
-                              block_table=block_table)
+                              block_table=block_table, layer=layer)
     elif kind == "mla":
         y, new_lc = _mla_mix(cfg, p["mix"], hn, mode=mode, lc=lc, pos=pos)
     else:
@@ -431,7 +434,11 @@ def _run_stack(cfg: ModelConfig, params: dict, h: jax.Array, *, mode: str,
     state built inside the layer body); prefill/decode thread the cache
     through the scan as per-layer xs/ys.  ``block_table`` (paged
     decode) is one [B, MB] map shared by every layer — it enters the
-    scan body as a captured constant, not a scanned-over leaf.
+    scan body as a captured constant, not a scanned-over leaf — and
+    the stacked pool rides the scan's carry instead: each layer
+    scatters its token into the stack at its own index and the kernel
+    reads that layer in place, so no layer slices the pool out of the
+    stack or writes it back.
     """
     kinds = cfg.block_kinds
     remat = cfg.remat and mode == "full" and cfg.remat_policy != "none"
@@ -445,6 +452,23 @@ def _run_stack(cfg: ModelConfig, params: dict, h: jax.Array, *, mode: str,
         ckpt = (jax.checkpoint if policy is None else
                 (lambda f: jax.checkpoint(f, policy=policy)))
     batch = h.shape[0]
+
+    if block_table is not None:
+        # paged decode: a homogeneous attention stack, never remat'd
+        def paged_body(carry, xs):
+            hh, kv = carry
+            lp, layer = xs
+            hh, new_lc, aux = apply_layer(cfg, kinds[0], lp, hh,
+                                          mode=mode, lc=LayerCache(kv=kv),
+                                          pos=pos, block_table=block_table,
+                                          layer=layer)
+            return (hh, new_lc.kv), aux
+
+        layers = jnp.arange(cache_layers.kv.k.shape[0], dtype=jnp.int32)
+        (h, kv), aux = jax.lax.scan(
+            paged_body, (h, cache_layers.kv), (params["layers"], layers),
+            unroll=cfg.n_layers if cfg.scan_unroll else 1)
+        return h, LayerCache(kv=kv), jnp.sum(aux)
 
     if cfg.homogeneous:
         kind = kinds[0]
@@ -466,8 +490,7 @@ def _run_stack(cfg: ModelConfig, params: dict, h: jax.Array, *, mode: str,
             hh, new_lc, aux = apply_layer(cfg, kind, lp, hh, mode=mode,
                                           lc=lc, pos=pos,
                                           prefix_len=prefix_len,
-                                          xattn=xa, cross_kv=ckv,
-                                          block_table=block_table)
+                                          xattn=xa, cross_kv=ckv)
             return hh, (new_lc if mode != "full" else aux, aux)
 
         if remat:
@@ -494,8 +517,7 @@ def _run_stack(cfg: ModelConfig, params: dict, h: jax.Array, *, mode: str,
 
         def call(lp_, hh_, lc_, kind_=kind):
             return apply_layer(cfg, kind_, lp_, hh_, mode=mode, lc=lc_,
-                               pos=pos, prefix_len=prefix_len,
-                               block_table=block_table)
+                               pos=pos, prefix_len=prefix_len)
 
         if remat:
             call = ckpt(call)

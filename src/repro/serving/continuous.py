@@ -19,8 +19,14 @@ Invariants this module maintains (who may touch what):
   may write it.
 - **Hot path is in-graph.**  One jit'd ``lax.scan`` advances
   ``sync_every`` micro-steps with the KV pool donated
-  (``donate_argnums``) so the cache updates in place; the host syncs
-  once per window to harvest tokens and refill.  Refills prefill up to
+  (``donate_argnums``); the host syncs once per window to harvest
+  tokens and refill.  On the paged path the pool is written in place:
+  the stacked pool rides the window's and the layer loop's carries,
+  each layer scatters its token into it and the paged kernel reads its
+  layer of the stack, and the pool's head axis is padded to a lane
+  multiple so that its default device layout is the row-major one the
+  kernel reads (``attn.pool_head_dim``): no program copies, slices or
+  restacks the pool.  Refills prefill up to
   ``n_free`` prompts in ONE bucketed contiguous row cache whose rows
   are scattered straight into pool slots inside the same jit.
 - **Block ownership (paged pool, ``cfg.kv_block_size > 0``).**  KV
@@ -55,6 +61,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.controller import AdmissionController, DraftDepthController
+from repro.models import attention as attn
 from repro.models import transformer as tfm
 from repro.serving import sampling
 from repro.serving.sampling import SamplingParams
@@ -233,9 +240,10 @@ def paged_slot_write(pool, rows, slot_idx, table_rows, *,
     ``pool`` is a paged ``tfm.Cache`` (homogeneous all-attn: stacked
     pool-layout KV leaves); ``rows`` a contiguous row cache of batch
     ``nb`` whose first ``n_pref_blocks * block_size`` rows hold the
-    prefilled prompt.  ``table_rows`` [nb, MB] is each row's FULL
-    block-table row (prefill + decode-budget blocks, trash-padded);
-    the kv scatter is BLOCK-granular — one indexed write per leaf, no
+    prefilled prompt (written with their head axis zero-padded to the
+    pool's).  ``table_rows`` [nb, MB] is each row's FULL block-table
+    row (prefill + decode-budget blocks, trash-padded); the kv
+    scatter is BLOCK-granular — one indexed write per leaf, no
     per-row indirection.  Out-of-range ``slot_idx`` / table entries
     (bucket-padding rows) are dropped.  The per-slot ``pos`` row is
     rewritten wholesale (valid prompt prefix, -1 beyond), which also
@@ -245,10 +253,10 @@ def paged_slot_write(pool, rows, slot_idx, table_rows, *,
     P = n_pref_blocks * block_size
     tb = table_rows[:, :n_pref_blocks]                  # [nb, npb]
 
-    def blkify(x):   # [L, nb, P, K, hd] -> [L, nb, npb, bs, K, hd]
-        return x[:, :, :P].reshape(
-            x.shape[0], x.shape[1], n_pref_blocks, block_size,
-            *x.shape[3:])
+    def blkify(x):   # [L, nb, P, K, hd] -> [L, nb, npb, bs, K, hd_pool]
+        x = attn.pad_head(x[:, :, :P], pkv.k.shape[-1])
+        return x.reshape(x.shape[0], x.shape[1], n_pref_blocks,
+                         block_size, *x.shape[3:])
 
     k = pkv.k.at[:, tb].set(blkify(rkv.k).astype(pkv.k.dtype),
                             mode="drop")
@@ -388,8 +396,9 @@ class ContinuousBatchingEngine:
 
         # fused k-step window: sampling, emission masks, EOS/max-new
         # done-masks and position bookkeeping all stay on device; ONE
-        # host sync per window.  The pool is donated so the KV cache
-        # updates in place across the whole window.  ``eos`` [B] is
+        # host sync per window.  The pool is donated to the window;
+        # the paged pool is also written in place inside it (module
+        # docstring), the contiguous one is not.  ``eos`` [B] is
         # the per-slot stop token (-1 = none; token ids are >= 0 so it
         # never matches).  The per-slot PRNG key rides the scan carry:
         # the token written at absolute position q is sampled with

@@ -333,6 +333,54 @@ def test_paged_parity_with_contiguous_across_refills():
     assert stats["prefill_calls"] >= 3           # several refill waves
 
 
+@pytest.mark.parametrize("impl", ["auto", "pallas"])
+def test_paged_parity_with_contiguous_three_layers(impl):
+    """Three layers deep, where a layer index mixed up with another
+    (the layer loop carries the whole stacked pool and each layer
+    writes and reads its own layer of it) cannot hide as it could in
+    the two-layer smoke stack: the paged pool must produce
+    byte-identical greedy tokens to the contiguous oracle across
+    refills, both through the jnp path and through the kernels (the
+    table-native paged kernel against the contiguous decode kernel)."""
+    cfg = _smoke_cfg().replace(n_layers=3, attn_impl=impl)
+    params = tfm.init_lm(cfg, KEY)
+    mk = _seeded_workload(cfg, n=5)
+    rc = mk()
+    ContinuousBatchingEngine(cfg, params, n_slots=2, max_seq=32,
+                             sync_every=2).serve(rc, prompt_len=8)
+    rp = mk()
+    stats = ContinuousBatchingEngine(
+        _paged(cfg), params, n_slots=2, max_seq=32,
+        sync_every=2).serve(rp, prompt_len=8)
+    assert [r.generated for r in rp] == [r.generated for r in rc]
+    assert all(r.done for r in rp)
+    assert stats["mode"] == "paged"
+    assert stats["prefill_calls"] >= 3           # several refill waves
+
+
+def test_paged_cache_write_on_stacked_pool_writes_one_layer():
+    """A token written into layer l of a stacked pool lands where the
+    one-layer write into ``pool[l]`` puts it, and every other layer is
+    left as it was."""
+    from repro.models import attention as attn
+    L, B, K, hd, bs, mb = 3, 2, 2, 4, 4, 2
+    nb = 1 + B * mb
+    one = attn.init_paged_kv_cache(B, mb * bs, K, hd, n_blocks=nb,
+                                   block_size=bs, dtype=jnp.float32)
+    stack = jax.tree_util.tree_map(lambda x: jnp.stack([x] * L), one)
+    table = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    k_new = jnp.ones((B, 1, K, hd))
+    v_new = 2 * jnp.ones((B, 1, K, hd))
+    pos = jnp.asarray([5, 2], jnp.int32)
+    want = attn.paged_cache_write(one, k_new, v_new, pos, table, bs)
+    got = attn.paged_cache_write(stack, k_new, v_new, pos, table, bs,
+                                 jnp.int32(1))
+    for leaf_got, leaf_one, leaf_want in zip(got, one, want):
+        np.testing.assert_array_equal(leaf_got[1], leaf_want)
+        for other in (0, 2):
+            np.testing.assert_array_equal(leaf_got[other], leaf_one)
+
+
 def test_paged_native_kernel_token_parity_end_to_end():
     """The table-native paged flash-decode kernel (attn_impl="pallas",
     interpret mode on CPU) must produce byte-identical greedy tokens

@@ -1,5 +1,7 @@
 """Per-kernel validation: shape/dtype sweeps + hypothesis properties,
 each Pallas kernel (interpret mode) vs its pure-jnp ref.py oracle."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -287,6 +289,88 @@ def test_paged_native_property(b, g, k, mb, win, seed):
     orf = ref.decode_attention(q, kc, vc, pos, cur, window=win)
     np.testing.assert_allclose(np.array(o_nat), np.array(orf),
                                rtol=5e-5, atol=5e-5)
+
+
+@pytest.mark.parametrize("win", [0, 11])
+def test_paged_native_reads_each_layer_of_a_stacked_pool(win):
+    """On a stacked pool [L, NB, bs, K, hd] the layer-indexed kernel —
+    the layer a traced scalar, as in the decode stack's layer loop —
+    equals the one-pool call on ``pool[l]`` byte for byte, for every
+    l; so do the gather shim and the jnp oracle given the same
+    arguments.  Every layer holds other values and the trash block
+    1e3, so reading a wrong layer or block shows.  Slot 0 maps two
+    blocks and leaves the rest on the trash block; the slots sit at
+    different positions."""
+    L, B, H, K, hd, bs, mb = 3, 2, 4, 2, 16, 8, 4
+    C = mb * bs
+    ks = jax.random.split(jax.random.PRNGKey(3), 2 * L + 1)
+    q = jax.random.normal(ks[-1], (B, H, hd))
+    pools = [_scatter_to_pool(jax.random.normal(ks[2 * i], (B, K, C, hd)),
+                              jax.random.normal(ks[2 * i + 1],
+                                                (B, K, C, hd)),
+                              bs, mb, seed=7, trash_fill=1e3)
+             for i in range(L)]
+    kst = jnp.stack([p[0] for p in pools])
+    vst = jnp.stack([p[1] for p in pools])
+    table = np.asarray(pools[0][2]).copy()
+    table[0, 2:] = 0
+    table = jnp.asarray(table)
+    lens = np.array([13, C - 3])
+    kv_pos = np.full((B, C), -1, np.int32)
+    for b in range(B):
+        kv_pos[b, :lens[b]] = np.arange(lens[b])
+    kv_pos = jnp.asarray(kv_pos)
+    cur = jnp.asarray(lens - 1, dtype=jnp.int32)
+
+    def per_layer(fn):
+        return jax.lax.map(lambda l: fn(q, kst, vst, table, kv_pos, cur,
+                                        l),
+                           jnp.arange(L, dtype=jnp.int32))
+
+    o_nat = per_layer(functools.partial(dak.paged_decode_attention,
+                                        window=win))
+    o_shim = per_layer(functools.partial(
+        dak.paged_decode_attention_shim, window=win, k_blk=bs))
+    o_ref = per_layer(functools.partial(ops.paged_decode_attention,
+                                        window=win, impl="ref"))
+    for i in range(L):
+        one = dak.paged_decode_attention(q, kst[i], vst[i], table, kv_pos,
+                                         cur, window=win)
+        assert bool(jnp.all(o_nat[i] == one))
+        assert bool(jnp.all(o_shim[i] == dak.paged_decode_attention_shim(
+            q, kst[i], vst[i], table, kv_pos, cur, window=win, k_blk=bs)))
+        assert bool(jnp.all(o_ref[i] == ops.paged_decode_attention(
+            q, kst[i], vst[i], table, kv_pos, cur, window=win,
+            impl="ref")))
+        np.testing.assert_allclose(np.array(o_nat[i]), np.array(o_ref[i]),
+                                   rtol=3e-5, atol=3e-5)
+    assert not bool(jnp.all(o_nat[0] == o_nat[1]))
+
+
+def test_paged_native_on_a_pool_padded_past_the_head_size():
+    """Pool rows zero-padded from the model's head size to a lane
+    multiple (``attn.pool_head_dim``, as the paged cache stores them)
+    give what the unpadded pool gives: the gather shim and the jnp
+    oracle drop the pad lanes, byte for byte, and the kernel pads q
+    with zeros, so the pad lanes add only zero products."""
+    from repro.models import attention as attn
+    q, k, v, kp, vp, table, kv_pos, cur = _paged_case(tail_empty=3)
+    hdp = attn.pool_head_dim(kp.shape[-1])
+    assert hdp > kp.shape[-1]
+    kpp, vpp = attn.pad_head(kp, hdp), attn.pad_head(vp, hdp)
+    bs = kp.shape[1]
+    o_nat = dak.paged_decode_attention(q, kpp, vpp, table, kv_pos, cur)
+    assert o_nat.shape == q.shape
+    np.testing.assert_allclose(
+        np.array(o_nat),
+        np.array(dak.paged_decode_attention(q, kp, vp, table, kv_pos,
+                                            cur)),
+        rtol=1e-6, atol=1e-6)
+    for fn in (functools.partial(dak.paged_decode_attention_shim,
+                                 k_blk=bs),
+               functools.partial(ops.paged_decode_attention, impl="ref")):
+        assert bool(jnp.all(fn(q, kpp, vpp, table, kv_pos, cur)
+                            == fn(q, kp, vp, table, kv_pos, cur)))
 
 
 def test_gather_block_views_rejects_ragged_extent():

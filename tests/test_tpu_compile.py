@@ -23,6 +23,10 @@ from repro.configs import get_config
 from repro.kernels import decode_attention as dak
 from repro.kernels import entropy as entk
 from repro.kernels import flash_attention as fak
+from repro.kernels import runtime
+from repro.models import attention as attn
+from repro.models import transformer as tfm
+from repro.serving.continuous import ContinuousBatchingEngine
 
 CFG = get_config("stablelm-3b")
 B, BS, MAX_SEQ = 8, 16, 512
@@ -86,6 +90,15 @@ def test_paged_decode_attention_compiles(one_chip):
     _compile(one_chip, dak.paged_decode_attention, *PAGED)
 
 
+def test_paged_decode_attention_compiles_on_stacked_pool(one_chip):
+    """The serving path's call: a stack of four layers' pools, read at
+    a layer index that arrives as a traced operand."""
+    q, kp, vp, *rest = PAGED
+    stack = tuple(((4,) + shape, dt) for shape, dt in (kp, vp))
+    _compile(one_chip, dak.paged_decode_attention, q, *stack, *rest,
+             ((), jnp.int32))
+
+
 def test_decode_attention_compiles(one_chip):
     _compile(one_chip, dak.decode_attention, *DECODE)
 
@@ -118,3 +131,81 @@ def test_kernel_keeps_its_name_in_any_caller(one_chip, name, fn, shapes):
 @pytest.mark.parametrize("vocab", [CFG.vocab, 2])
 def test_entropy_stats_compiles(one_chip, vocab):
     _compile(one_chip, entk.entropy_stats, ((32, vocab), jnp.float32))
+
+
+# the decode-path programs at stablelm-3b widths, three layers deep
+# (three so that a layer index mixed up with another cannot pass)
+POOL_LAYERS, PLEN = 3, 128
+POOL_CFG = CFG.replace(n_layers=POOL_LAYERS, kv_block_size=BS,
+                       attn_impl="pallas", remat=False)
+
+
+def _abstract(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=sharding), tree)
+
+
+def _program_operands(eng, program: str, sharding) -> tuple:
+    """Abstract operands of one of the engine's pool programs: the
+    fused decode window, a full prefill wave, a one-row insert."""
+    def a(dt, *shape):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    i32, f32 = jnp.int32, jnp.float32
+    pool = _abstract(jax.eval_shape(
+        lambda: tfm.init_cache(POOL_CFG, B, MAX_SEQ)), sharding)
+    state = (a(i32, B, 1), a(i32, B), a(bool, B), a(i32, B))
+    if program == "window":
+        return (eng.params, pool, *state, a(i32, B),
+                a(jnp.uint32, B, 2), a(f32, B), a(i32, B), a(f32, B))
+    if program == "prefill":
+        return (eng.params, a(i32, B, PLEN), pool, a(i32, B),
+                a(i32, B, MB), *state, a(i32, B), a(i32, B), a(i32, B),
+                a(jnp.uint32, B, 2), a(f32, B), a(i32, B), a(f32, B))
+    rows = _abstract(jax.eval_shape(lambda: tfm.init_cache(
+        POOL_CFG, 1, PLEN, layout="contiguous")), sharding)
+    return (pool, rows, a(i32, 1), a(i32, 1, MB), a(i32, 1), a(i32, 1),
+            *state, a(i32, 1), a(i32, B), a(i32, 1))
+
+
+@pytest.mark.parametrize("program", ["window", "prefill", "insert"])
+def test_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch,
+                                               program):
+    """The paged pool programs — the fused decode window (8 slots x
+    512, 16-row blocks, 8 micro-steps), a prefill wave of 8 and a
+    disaggregated insert — hold no op that copies, slices or rewrites
+    a whole K/V pool.  The only ops that produce the stacked
+    [L, NB, bs, K, hdp] or a one-layer [NB, bs, K, hdp] pool shape are
+    the scatters that write new rows into it, the pool's parameters and
+    the loop plumbing that passes it on: the layer loop carries the
+    stacked pool, the kernel reads its layer in place, and the pool's
+    head axis, padded from 80 to 128 lanes, makes the device's default
+    layout of the pool the row-major one the kernel reads (at 80 it
+    puts the block axis minor-most, and each program would convert
+    both pools at entry and exit)."""
+    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    params = _abstract(jax.eval_shape(
+        lambda: tfm.init_lm(POOL_CFG, jax.random.PRNGKey(0))), one_chip)
+    eng = ContinuousBatchingEngine(POOL_CFG, params, n_slots=B,
+                                   max_seq=MAX_SEQ, sync_every=8)
+    fn = {"window": eng._step_k,
+          "prefill": eng._prefill_bucket_paged(B, PLEN),
+          "insert": eng._insert_bucket_paged(PLEN)}[program]
+    text = fn.lower(*_program_operands(eng, program, one_chip)) \
+        .compile().as_text()
+    if program == "window":
+        assert "tpu_custom_call" in text
+    nb = eng.pool_blocks
+    hdp = attn.pool_head_dim(HD)
+    pool = rf"bf16\[(?:{POOL_LAYERS},)?{nb},{BS},{K},{hdp}\]"
+    made = re.findall(rf"^\s*(?:ROOT )?%(\S+) = {pool}\S* ([\w-]+)\("
+                      rf"([^\n]*)$", text, re.M)
+    assert made
+    passing = {"parameter", "get-tuple-element", "tuple", "bitcast",
+               "while", "scatter"}
+    bad = [name for name, op, rest in made
+           if op not in passing
+           and not (op == "fusion" and re.search(
+               r'op_name="[^"]*/scatter"', rest))]
+    assert not bad, bad
