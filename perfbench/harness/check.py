@@ -80,7 +80,7 @@ def compare(cell, seed: int, picked: list, *, control: bool = False
     max_out = int(cell.traffic["output"]["max"])
     rows = int(cell.setup["check"]["rows_per_block"])
     S = plen + max_out - 1
-    params = weights.make_params(cfg, seed)
+    params = weights.make_params(cfg, seed, cell.bench_dir)
     prog, ctl = [], []
     for b in range(0, len(picked), rows):
         blk = picked[b:b + rows]

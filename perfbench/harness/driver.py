@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import gc
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, fields
 
 import numpy as np
 
@@ -49,7 +49,8 @@ class Mark:
     """The run's state at one instant of the window."""
     t: float
     seen: dict                        # rid -> tokens visible
-    counters: dict                    # the decode session's counters
+    counters: dict                    # the decode session's integer
+                                      # counters
     queue_area: float                 # integral of n_queued from 0 to t
     tokens: int                       # tokens visible in all
 
@@ -106,19 +107,19 @@ class _CompileCounter:
 
 
 def model_config(cell):
-    """The program's ``ModelConfig`` as the cell runs it: the registry
-    entry of the configuration's architecture with every size taken
-    from the configuration file, on a paged pool."""
+    """The program's ``ModelConfig`` as the cell runs it: every key of
+    the configuration file that names one of its fields (``source``
+    aside; a list as a tuple), the registry entry of the file's
+    ``program_arch`` for what the file leaves out, on a paged pool."""
     from repro.configs import get_config
-    c = cell.config
-    s = cell.setup
-    base = get_config(c["program_arch"])
-    keys = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-            "d_ff", "vocab", "rope_pct", "rope_theta", "norm", "act",
-            "qkv_bias", "tie_embeddings", "dtype")
-    return base.replace(**{k: c[k] for k in keys},
-                        kv_block_size=int(s["kv_block_size"]),
-                        draft_layers=0, temperature=0.0, remat=False)
+    base = get_config(cell.config["program_arch"])
+    named = {f.name for f in fields(base)} - {"source"}
+    sizes = {k: tuple(v) if isinstance(v, list) else v
+             for k, v in cell.config.items() if k in named}
+    return base.replace(**{**sizes,
+                           "kv_block_size": int(cell.setup["kv_block_size"]),
+                           "draft_layers": 0, "temperature": 0.0,
+                           "remat": False})
 
 
 class System:
@@ -193,13 +194,13 @@ class System:
         gc.collect()
 
     def counters(self) -> dict:
+        """Every integer counter of the decode session (none before the
+        first request opens one)."""
         sess = self.port.session
         if sess is None:
-            return {"decode_steps": 0, "occupied_slot_steps": 0,
-                    "host_syncs": 0, "prefill_calls": 0}
-        st = sess.stats()
-        return {k: st[k] for k in ("decode_steps", "occupied_slot_steps",
-                                   "host_syncs", "prefill_calls")}
+            return {}
+        return {k: v for k, v in sess.stats().items()
+                if isinstance(v, int) and not isinstance(v, bool)}
 
     def n_queued(self) -> int:
         sess = self.port.session

@@ -10,7 +10,10 @@ operands each call must read or write at least once in HBM, at the
 served dtype.
 
 ``cfg`` is a configuration file's dict: n_layers, d_model, n_heads,
-n_kv_heads, head_dim, d_ff, vocab, dtype.
+n_kv_heads, head_dim, d_ff, vocab, dtype.  ``ref`` is its reference
+module: where it defines ``layer_matmul_params(cfg)`` or
+``attn_pair_flops(cfg)``, the architecture's own count takes the place
+of the dense decoder's below.
 """
 from __future__ import annotations
 
@@ -40,24 +43,31 @@ def attn_pair_flops(cfg: dict) -> int:
     return 4 * cfg["n_heads"] * cfg["head_dim"]
 
 
-def decode_token_flops(cfg: dict, ctx: int) -> int:
+def _counts(cfg: dict, ref) -> tuple[int, int]:
+    """(linear-map weights per layer, operations per pair per layer):
+    the reference's own where it defines them, else the dense ones."""
+    lm = getattr(ref, "layer_matmul_params", layer_matmul_params)
+    ap = getattr(ref, "attn_pair_flops", attn_pair_flops)
+    return lm(cfg), ap(cfg)
+
+
+def decode_token_flops(cfg: dict, ctx: int, ref=None) -> int:
     """One decode step of one sequence whose new token attends to
     ``ctx`` rows (itself included): every layer, and the head."""
     L = cfg["n_layers"]
-    return (2 * (L * layer_matmul_params(cfg) + head_params(cfg))
-            + L * attn_pair_flops(cfg) * ctx)
+    layer, pair = _counts(cfg, ref)
+    return 2 * (L * layer + head_params(cfg)) + L * pair * ctx
 
 
-def prefill_request_flops(cfg: dict, plen: int) -> int:
+def prefill_request_flops(cfg: dict, plen: int, ref=None) -> int:
     """Prefill of one ``plen``-token prompt: every position through
     every layer (causal attention: ``plen (plen + 1) / 2`` pairs), and
     the head at the last position only, which is all the first token
     needs."""
     L = cfg["n_layers"]
+    layer, pair = _counts(cfg, ref)
     pairs = plen * (plen + 1) // 2
-    return (2 * L * layer_matmul_params(cfg) * plen
-            + L * attn_pair_flops(cfg) * pairs
-            + 2 * head_params(cfg))
+    return 2 * L * layer * plen + L * pair * pairs + 2 * head_params(cfg)
 
 
 def paged_decode_kernel(cfg: dict, ctx: int) -> tuple[int, int]:

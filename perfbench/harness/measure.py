@@ -117,9 +117,16 @@ class Context:
                    if a.seen.get(s.plan.rid, 0) == 0
                    and b.seen.get(s.plan.rid, 0) >= 1)
 
+    @property
+    def reference(self):
+        """The configuration's reference module (its work counts)."""
+        return spec.reference_module(self.cell)
+
     def counter_delta(self, key: str) -> int:
+        """The change of the decode session's integer counter ``key``
+        over the span; a counter is 0 before the session opens."""
         a, b = self._span()
-        return b.counters[key] - a.counters[key]
+        return b.counters.get(key, 0) - a.counters.get(key, 0)
 
 
 def per_layer(cell, ctx: Context) -> dict:

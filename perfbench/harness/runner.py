@@ -72,7 +72,7 @@ def run_cell(bench: spec.Benchmark, name: str, seed: int, seconds: float,
     plan = traffic.plan(cell.traffic, seconds, seed,
                         int(cell.config["vocab"]),
                         rate_qps=cell.setup.get("rate_qps"))
-    params = weights.make_params(cell.config, seed)
+    params = weights.make_params(cell.config, seed, cell.bench_dir)
     jax.block_until_ready(params)
     system = driver.System(cell, params)
     del params

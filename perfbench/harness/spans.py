@@ -1,5 +1,4 @@
-"""The serving program's own spans in a profiler trace, and the
-device's idle time put down to them.
+"""The device's idle time put down to the serving program's own spans.
 
 The program scopes the host work at its layer boundaries with profiler
 annotations (``repro.telemetry.trace.Tracer.scope``): ``server.*`` in
@@ -9,6 +8,8 @@ their results on the host.  A profiler session records them on the
 host plane, beside the benchmark's own ``bench.*`` annotations and on
 the clock of the device planes, so an instant in which the chip ran
 nothing can be named by the innermost program span the host was in.
+``harness.trace.load`` keeps them, under any prefix, in
+``Trace.spans``.
 
 Interval arithmetic only, on :class:`harness.trace.Event`; the
 program's spans nest (one serving thread, context managers), so the
@@ -21,25 +22,6 @@ from collections import defaultdict
 
 from harness import trace as tm
 from harness.trace import Event
-
-# the program's layers, by the prefix of their span names
-PREFIXES = ("server.", "sched.", "step.")
-
-
-def load(path: str) -> list[Event]:
-    """The program spans on the host plane of the trace at ``path``,
-    sorted by start."""
-    from jax.profiler import ProfileData
-    out = []
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name != tm.HOST_PLANE:
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith(PREFIXES):
-                    out.append(Event(e.name, int(e.start_ns),
-                                     int(e.start_ns + e.duration_ns)))
-    return sorted(out, key=lambda e: (e.start, -e.end))
 
 
 def innermost(spans: list[Event]) -> list[tuple[int, int, str]]:

@@ -14,13 +14,43 @@ benchmark's directory, and this module finds it by the name that
 
 Adding a cell, a configuration, a mix or a metric therefore means
 adding files and entries, never editing a file that is there.
+
+A configuration of any architecture the program serves enters so.
+Its file holds every size as the program's ``ModelConfig`` names it
+(``harness.driver.model_config`` takes each such key, lists as
+tuples; the registry entry named by ``program_arch`` gives only what
+the file leaves out), and names its reference module, which defines:
+
+    logits(cfg, params, tokens, start, quant=None)
+        next-token logits [B, S - start, vocab] in float32 at the
+        highest matmul precision; ``quant="int8"`` is the control
+    make_params(cfg, key)
+        the weights for a PRNG key, in the program's tree of this
+        architecture, bf16 where served and norms in float32, on the
+        device in one jitted call
+
+and may define the work one token needs, which ``harness.flops``
+takes in place of the dense decoder's formulas:
+
+    layer_matmul_params(cfg)
+        weights of the linear maps one token touches in one layer
+    attn_pair_flops(cfg)
+        operations per (query, key) pair in one layer
+
+A per-layer metric reads what :class:`harness.measure.Context` holds:
+the window's stamps, every integer counter of the decode session at
+the span's ends, and with a trace the device's operations and
+programs, the benchmark's ``bench.*`` annotations and the program's
+own spans under any prefix (``harness.trace``).
 """
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
 import os
 import re
+import sys
 from dataclasses import dataclass, field
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -149,23 +179,37 @@ def metric_file(bench_dir: str, name: str) -> str:
     return os.path.join(bench_dir, "metrics", name + ".py")
 
 
-def _load_module(path: str, modname: str):
-    spec = importlib.util.spec_from_file_location(modname, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def _load_module(path: str, kind: str):
+    """The module at ``path``, executed once per process: a later call
+    with the same path gets the same module, so its jitted functions
+    keep their caches."""
+    path = os.path.abspath(path)
+    modname = (f"perfbench_{kind}_"
+               + hashlib.sha256(path.encode()).hexdigest()[:16])
+    if modname not in sys.modules:
+        spec = importlib.util.spec_from_file_location(modname, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[modname] = mod
+    return sys.modules[modname]
 
 
 def metric_reader(bench_dir: str, name: str):
     """The ``read(ctx)`` function of ``metrics/<name>.py``."""
-    mod = _load_module(metric_file(bench_dir, name),
-                       "perfbench_metric_" + re.sub(r"\W", "_", name))
+    return _load_module(metric_file(bench_dir, name), "metric")
+
+
+def reference(bench_dir: str, name: str):
+    """The reference module ``configs/<name>.py`` under ``bench_dir``;
+    one that lacks ``logits`` or ``make_params`` is refused."""
+    mod = _load_module(os.path.join(bench_dir, "configs", name + ".py"),
+                       "ref")
+    for fn in ("logits", "make_params"):
+        _check(callable(getattr(mod, fn, None)),
+               f"reference {name} defines no {fn}")
     return mod
 
 
 def reference_module(cell: Cell):
     """The plain reference named by the cell's configuration."""
-    ref = cell.config["reference"]
-    return _load_module(os.path.join(cell.bench_dir, "configs",
-                                     ref + ".py"),
-                        "perfbench_ref_" + re.sub(r"\W", "_", ref))
+    return reference(cell.bench_dir, cell.config["reference"])

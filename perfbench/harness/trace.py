@@ -4,8 +4,14 @@ Read with ``jax.profiler.ProfileData`` alone.  A TPU trace has one
 plane per chip (``/device:TPU:<n>``) whose ``XLA Ops`` line holds one
 event per operation run on the chip and whose ``XLA Modules`` line
 holds one event per program execution (``jit_<name>(<id>)``); the
-host plane (``/host:CPU``) holds the benchmark's own annotations
-(``bench.*``), on the same clock.
+host plane (``/host:CPU``) holds, on the same clock, the benchmark's
+own annotations (``bench.*``) and the program's spans: the
+``Tracer.scope`` annotations at its layer boundaries, each named
+``<layer>.<what>`` in lower case (``server.push``, ``sched.advance``,
+``step.window``, and whatever prefix a new mechanism brings).  The
+runtime's own host events are named otherwise (``PjitFunction(step_k)``,
+``tpu::System::Execute``, ``np.asarray(jax.Array)``, ``shard_args``,
+and on a CPU the operations themselves, ``fusion.12``).
 
 Everything here is arithmetic on intervals: busy time is the union of
 the operations' intervals, a program's or a kernel's time is the sum
@@ -18,6 +24,7 @@ from __future__ import annotations
 
 import glob
 import os
+import re
 from collections import defaultdict
 from dataclasses import dataclass, field
 
@@ -26,6 +33,9 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 ANNOTATION_PREFIX = "bench."
+# a program span's name: ``<layer>.<what>``, lower case, each part
+# starting with a letter (an operation's name ends in ``.<number>``)
+SPAN_NAME = re.compile(r"[a-z][a-z0-9_]*(?:\.[a-z_][a-z0-9_]*)+")
 
 
 @dataclass
@@ -40,6 +50,7 @@ class Trace:
     devices: dict = field(default_factory=dict)    # plane -> [Event] ops
     modules: dict = field(default_factory=dict)    # plane -> [Event]
     host: list = field(default_factory=list)       # bench.* annotations
+    spans: list = field(default_factory=list)      # the program's spans
     t0: int = 0                                    # traced window (ns)
     t1: int = 0
 
@@ -65,7 +76,9 @@ def short_name(name: str) -> str:
 def load(path: str, window: tuple[int, int] | None = None) -> Trace:
     """Parse ``path``.  The window is the ``bench.window`` annotation
     when the host plane has one, else ``window``, else the span of the
-    device events."""
+    device events.  Device events are kept inside the window; the
+    program's spans whole, sorted by start (the outer of two that start
+    together first)."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     tr = Trace()
@@ -86,9 +99,13 @@ def load(path: str, window: tuple[int, int] | None = None) -> Trace:
             for line in plane.lines:
                 for e in line.events:
                     if e.name.startswith(ANNOTATION_PREFIX):
-                        tr.host.append(Event(
-                            e.name, int(e.start_ns),
-                            int(e.start_ns + e.duration_ns)))
+                        kept = tr.host
+                    elif SPAN_NAME.fullmatch(e.name):
+                        kept = tr.spans
+                    else:
+                        continue
+                    kept.append(Event(e.name, int(e.start_ns),
+                                      int(e.start_ns + e.duration_ns)))
     marks = [e for e in tr.host if e.name == "bench.window"]
     if marks:
         tr.t0, tr.t1 = marks[0].start, marks[0].end
@@ -104,6 +121,7 @@ def load(path: str, window: tuple[int, int] | None = None) -> Trace:
                            if e.end > tr.t0 and e.start < tr.t1),
                           key=lambda e: e.start)
     tr.host.sort(key=lambda e: e.start)
+    tr.spans.sort(key=lambda e: (e.start, -e.end))
     return tr
 
 

@@ -7,6 +7,7 @@ file sets the fixed rate it runs at.  Keys:
                     earlier requests were answered; "closed":
                     ``clients`` callers, each sending its next request
                     the moment its previous reply completes
+    arrivals        open loop: "poisson" or "poisson_quantiles" (below)
     rate_qps        open loop: mean arrival rate (the cell may set it)
     clients         closed loop: number of callers, no think time
     per_client      closed loop: requests planned per caller, more
@@ -25,6 +26,14 @@ draws ``n + 1`` arrival times and the plan scales them so the
 conditioned on ``n`` arrivals in the window (its arrival times are
 then uniform order statistics), so bursts and lulls come as Poisson
 traffic brings them, while every seed sends the same amount of work.
+
+``"poisson_quantiles"`` holds the gaps, too, to one set for every
+seed: the ``n + 1`` midpoint quantiles of the exponential
+distribution, in a seeded order, scaled as above.  Bursts and lulls
+are those of Poisson traffic, each gap as often as Poisson brings it,
+and the seed decides only where they fall; with ``"poisson"`` the seed
+also decides how bursty the window is, which moves a run's latency
+percentiles where every prefill stalls the requests in flight.
 
 Output lengths are the same set for every seed, the midpoint
 quantiles of the clipped lognormal, in a seeded order over the whole
@@ -56,6 +65,16 @@ def window_arrivals(n: int, rate_qps: float, seconds: float,
     return times[:n] * (seconds / times[n])
 
 
+def quantile_arrivals(n: int, seconds: float, seed) -> np.ndarray:
+    """``n`` arrival times in ``[0, seconds)`` from the same ``n + 1``
+    gaps for every seed, the exponential's midpoint quantiles, in a
+    seeded order, scaled so the last gap ends at ``seconds``."""
+    u = (np.arange(n + 1) + 0.5) / (n + 1)
+    gaps = np.random.default_rng(seed).permutation(-np.log1p(-u))
+    times = np.cumsum(gaps)
+    return times[:n] * (seconds / times[n])
+
+
 def stratified_lognormal(n: int, median: float, sigma: float, lo: int,
                          hi: int, rng: np.random.Generator) -> np.ndarray:
     """``n`` clipped lognormal integers at the midpoint quantiles, in a
@@ -82,10 +101,10 @@ def plan(traffic: dict, seconds: float, seed: int, vocab: int,
     """Every request a run of ``seconds`` may send, in plan order.
 
     Open loop: ``round(rate * seconds)`` Poisson arrivals inside the
-    window.  Closed loop: ``per_client`` requests for each of the
-    ``clients`` callers, dealt client by client (request ``i`` belongs
-    to caller ``i % clients``), each due when its caller's previous
-    reply completes."""
+    window, drawn as the mix's ``arrivals`` says.  Closed loop:
+    ``per_client`` requests for each of the ``clients`` callers, dealt
+    client by client (request ``i`` belongs to caller ``i % clients``),
+    each due when its caller's previous reply completes."""
     rng = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, 0])
     plen = int(traffic["prompt_len"])
     o = traffic["output"]
@@ -94,8 +113,14 @@ def plan(traffic: dict, seconds: float, seed: int, vocab: int,
         rate = float(rate_qps if rate_qps is not None
                      else traffic["rate_qps"])
         n = max(int(round(rate * seconds)), 1)
-        due = list(window_arrivals(n, rate, seconds,
-                                   [seed & 0xFFFFFFFF, seed >> 32, 1]))
+        key = [seed & 0xFFFFFFFF, seed >> 32, 1]
+        kind = traffic.get("arrivals", "poisson")
+        if kind == "poisson":
+            due = list(window_arrivals(n, rate, seconds, key))
+        elif kind == "poisson_quantiles":
+            due = list(quantile_arrivals(n, seconds, key))
+        else:
+            raise ValueError(f"unknown arrivals {kind!r}")
         clients = [-1] * n
     elif loop == "closed":
         c = int(traffic["clients"])

@@ -15,5 +15,6 @@ def read(ctx):
     ctxs = ctx.decode_contexts()
     if t <= 0 or len(ctxs) == 0:
         return None
-    work = float(flops.decode_token_flops(ctx.cfg, ctxs).sum())
+    work = float(flops.decode_token_flops(ctx.cfg, ctxs,
+                                            ctx.reference).sum())
     return 100.0 * work / (t * ctx.peaks["flops_per_s"])

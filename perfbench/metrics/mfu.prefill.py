@@ -16,5 +16,6 @@ def read(ctx):
     n = ctx.prefilled()
     if t <= 0 or n == 0:
         return None
-    work = n * flops.prefill_request_flops(ctx.cfg, ctx.plen)
+    work = n * flops.prefill_request_flops(ctx.cfg, ctx.plen,
+                                         ctx.reference)
     return 100.0 * work / (t * ctx.peaks["flops_per_s"])

@@ -1,0 +1,11 @@
+"""server.host_idle_pct (server layer): the share of the traced span,
+in percent, in which the chip ran nothing and the host's innermost
+program span was one of ``Server``'s (``server.*``: push, poke,
+admission, absorbing responses).  Moves tpot_p50_ms."""
+from harness import spans
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    return spans.host_idle_pct(ctx.trace, ctx.trace.spans, "server.")

@@ -40,7 +40,8 @@ def main(argv=None) -> int:
     cell = bench.cell(args.workload)
     runner.device_info(cell.chips)
     runner.enable_compile_cache(ROOT)
-    params = weights.make_params(cell.config, args.seed)
+    params = weights.make_params(cell.config, args.seed,
+                                 cell.bench_dir)
     jax.block_until_ready(params)
     system = driver.System(cell, params)
     del params

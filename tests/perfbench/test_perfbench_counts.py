@@ -6,9 +6,9 @@ import os
 
 import numpy as np
 import pytest
-from perfbench_testlib import REPO
+from perfbench_testlib import MOE_CELL, REPO
 
-from harness import flops, traffic
+from harness import flops, measure, spec, traffic
 
 
 def _cfg(name):
@@ -53,6 +53,41 @@ def test_decode_and_prefill_flops(name):
     assert flops.prefill_request_flops(cfg, plen) == (
         2 * L * h["layer"] * plen + L * h["pair"] * (128 * 129 // 2)
         + 2 * h["head"])
+
+
+@pytest.mark.parametrize("name", sorted(HAND))
+def test_the_dense_reference_keeps_the_dense_counts(name):
+    """The dense reference defines no counts of its own: the counts
+    the mfu readers take through it are the hand counts above."""
+    cfg, h = _cfg(name), HAND[name]
+    ref = spec.reference(os.path.join(REPO, "perfbench"), "dense_decoder")
+    assert not hasattr(ref, "layer_matmul_params")
+    assert not hasattr(ref, "attn_pair_flops")
+    L = h["L"]
+    ctxs = np.array([129, 200, 383])
+    assert list(flops.decode_token_flops(cfg, ctxs, ref)) == [
+        2 * (L * h["layer"] + h["head"]) + L * h["pair"] * c for c in ctxs]
+    assert flops.prefill_request_flops(cfg, 2048, ref) == (
+        flops.prefill_request_flops(cfg, 2048))
+
+
+def test_a_reference_gives_its_own_counts(moe_root):
+    """A sparse-expert reference counts top-k of the experts held and
+    the router; the attention's pairs stay as they are."""
+    cell = spec.load(moe_root).cell(MOE_CELL)
+    cfg, ref = cell.config, spec.reference_module(cell)
+    d, E, k, f = 128, 4, 2, 64
+    layer = d * (4 + 2 * 2) * 32 + 4 * 32 * d + d * E + k * 3 * d * f
+    assert ref.layer_matmul_params(cfg) == layer
+    assert flops.decode_token_flops(cfg, 20, ref) == (
+        2 * (2 * layer + d * 512) + 2 * 4 * 4 * 32 * 20)
+    assert flops.prefill_request_flops(cfg, 16, ref) == (
+        2 * 2 * layer * 16 + 2 * 4 * 4 * 32 * (16 * 17 // 2)
+        + 2 * d * 512)
+    # the mfu readers take the work through the cell's reference
+    ctx = measure.Context(cell=cell, win=None, trace=None, device_kind="",
+                          slots=4, sync_every=4)
+    assert ctx.reference is ref
 
 
 def test_published_sizes():
@@ -143,6 +178,35 @@ def test_arrivals_are_poisson():
     var = np.var(first_quarter)
     assert 0.6 * n * 3 / 16 < var < 1.5 * n * 3 / 16
     assert 0.85 < np.mean(cvs) < 1.1
+
+
+def test_quantile_arrivals_hold_one_set_of_gaps():
+    """``poisson_quantiles``: every seed gets the same gaps, the
+    exponential's midpoint quantiles scaled to the window, in its own
+    order; so the seed moves where a burst falls, not how bursty the
+    window is."""
+    t = {"loop": "open", "arrivals": "poisson_quantiles", "prompt_len": 4,
+         "output": {"median": 8, "sigma": 0.5, "min": 2, "max": 16}}
+    n, secs = 160, 50.0
+    gaps, orders = [], []
+    for seed in (3, 2**31 + 7, 2**32 + 11):
+        due = np.array([p.due_s for p in
+                        traffic.plan(t, secs, seed, 100, rate_qps=n / secs)])
+        assert len(due) == n and 0 < due[0] and due[-1] < secs
+        assert np.all(np.diff(due) > 0)
+        g = np.diff(np.concatenate([[0.0], due, [secs]]))
+        gaps.append(np.sort(g))
+        orders.append(g)
+    u = (np.arange(n + 1) + 0.5) / (n + 1)
+    want = np.sort(-np.log1p(-u))
+    want *= secs / want.sum()
+    for g in gaps:
+        assert np.allclose(g, want)
+    assert not np.allclose(orders[0], orders[1])
+    assert 0.85 < want.std() / want.mean() < 1.1   # exponential's cv
+    t["arrivals"] = "uniform"
+    with pytest.raises(ValueError, match="arrivals"):
+        traffic.plan(t, secs, 3, 100, rate_qps=1.0)
 
 
 def test_closed_plan():

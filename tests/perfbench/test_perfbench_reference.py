@@ -8,6 +8,8 @@ functions the engine's prefill and window programs compose), greedy,
 in bf16.  The reference runs once over the prompt followed by the
 decoded tokens.
 """
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,6 +24,34 @@ PLEN, NEW, B = 16, 12, 3
 # logits of unit scale differ by ~1e-2 at this size; 0.08 leaves room
 # on both sides and is far below the spread of the logits (~1)
 LOGIT_ATOL = 0.08
+
+
+# sha256 over every leaf (path, dtype, shape, bytes) of the weights the
+# dense generator made before it moved into the dense reference
+DENSE_DIGESTS = {
+    ("tiny-gqa", 5):
+        "de9c333ae80f5a9bc11538457f174030ae11c0eabb4fe79a2a3f323d38a4d2e3",
+    ("tiny-gqa", 2**31 + 11):
+        "f4567e08216a35a37471c16eb92b1d8dec994da566f309190633d7feec04161c",
+    ("tiny-mha", 5):
+        "d6d2402fa29e480e923029ababc2f2dec5e304f200bb9e686b7c6c2758818c11",
+    ("tiny-mha", 2**31 + 11):
+        "adedbfbb4ea27ce84db3a7e8aa227e6cdd6f2ea4c5fe681abad2cc591b690d7d",
+}
+
+
+@pytest.mark.parametrize("name,seed", sorted(DENSE_DIGESTS))
+def test_dense_weights_are_bit_for_bit_the_same(tiny_bench, name, seed):
+    cell = tiny_bench.cell(name + ".chat")
+    params = weights.make_params(cell.config, seed, cell.bench_dir)
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        a = np.asarray(leaf)
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(str(a.dtype).encode())
+        h.update(str(a.shape).encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == DENSE_DIGESTS[name, seed]
 
 
 def _program_logits(cell, params, prompts):
@@ -58,7 +88,7 @@ def test_reference_matches_prefill_then_paged_decode(tiny_bench,
     cell = tiny_bench.cell(cell_name)
     cfg = cell.config
     ref = spec.reference_module(cell)
-    params = weights.make_params(cfg, 5)
+    params = weights.make_params(cfg, 5, cell.bench_dir)
     prompts = np.random.default_rng(5).integers(
         0, cfg["vocab"], (B, PLEN)).astype(np.int32)
     prog, toks = _program_logits(cell, params, prompts)
@@ -78,7 +108,7 @@ def _served(cell, seed, n=8, new=32):
     """Serve ``n`` greedy requests through the program's paged decode
     session (prefill wave + fused windows); Stamps of the answers."""
     from repro.serving.continuous import GenRequest
-    params = weights.make_params(cell.config, seed)
+    params = weights.make_params(cell.config, seed, cell.bench_dir)
     system = driver.System(cell, params)
     sess = system.engine.start_session(system.plen)
     rng = np.random.default_rng(seed)

@@ -75,7 +75,7 @@ def test_requests_due_in_the_window_are_sent_after_a_stall(tiny_bench):
     due inside the window, each stamped from its due time, so a stall
     near the close cannot hide its own victims."""
     cell = tiny_bench.cell("tiny-mha.chat")
-    params = weights.make_params(cell.config, 3)
+    params = weights.make_params(cell.config, 3, cell.bench_dir)
     system = driver.System(cell, params)
     plan = traffic.plan(cell.traffic, 1.0, 3, int(cell.config["vocab"]),
                         rate_qps=12.0)

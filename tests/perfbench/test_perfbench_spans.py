@@ -2,17 +2,22 @@
 of the chip put down to the innermost program span on the host, on
 hand-made intervals; the spans of a served window recorded by the
 profiler on the CPU; and the shared clock of a trace recorded on a TPU
-v5e."""
+v5e, with the three host-idle readers on it."""
 import gzip
 import os
 import shutil
+from types import SimpleNamespace
 
 import pytest
+from perfbench_testlib import REPO
 
-from harness import driver, traffic, weights
+from harness import driver, spec, traffic, weights
 from harness import spans as sm
 from harness import trace as tm
 from harness.trace import Event, Trace
+
+# the serving program's layers, by the prefix of their span names
+LAYERS = ("server.", "sched.", "step.")
 
 
 def _ev(name, a, b):
@@ -68,7 +73,7 @@ def test_idle_is_put_down_to_the_innermost_span(spanned):
         "server.poke": 1, "sched.advance": 3, "step.window": 6 + 11,
         "server.admit": 1, "sched.refill": 1, "step.prefill": 2,
         "sched.seat": 2, "server.absorb": 4, "server.push": 1}
-    shares = {p: sm.host_idle_pct(tr, program, p) for p in sm.PREFIXES}
+    shares = {p: sm.host_idle_pct(tr, program, p) for p in LAYERS}
     assert shares == pytest.approx({"server.": 7.0, "sched.": 6.0,
                                     "step.": 19.0})
     # the rest of the idle share is the benchmark's own: bench.idle
@@ -95,10 +100,13 @@ def test_shares_read_zero_or_nothing(spanned):
 def test_profiled_window_records_the_program_spans(tiny_bench, tmp_path):
     """The benchmark's own serving loop under a profiler session on the
     CPU: the program's spans reach the host plane with no tracer passed
-    in, and the harness's reader keeps only its own annotations."""
+    in; the harness keeps its own annotations in ``Trace.host`` and
+    the program's spans, and nothing of the runtime's, in
+    ``Trace.spans``."""
     import jax
     cell = tiny_bench.cell("tiny-mha.chat")
-    system = driver.System(cell, weights.make_params(cell.config, 5))
+    system = driver.System(cell, weights.make_params(cell.config, 5,
+                                                      cell.bench_dir))
     system.warm_up()
     plan = traffic.plan(cell.traffic, 1.0, 5, int(cell.config["vocab"]),
                         rate_qps=12.0)
@@ -110,16 +118,18 @@ def test_profiled_window_records_the_program_spans(tiny_bench, tmp_path):
     tr = tm.load(path)
     assert {e.name for e in tr.host} <= {"bench.window", "bench.push",
                                          "bench.poke", "bench.idle"}
-    program = sm.load(path)
+    program = tr.spans
+    assert program == sorted(program, key=lambda e: (e.start, -e.end))
     names = {e.name for e in program}
     assert {"server.push", "server.admit", "sched.advance",
             "sched.refill", "step.prefill", "sched.seat", "step.window",
             "sched.harvest", "server.absorb"} <= names
-    assert all(n.startswith(sm.PREFIXES) for n in names)
+    assert all(n.startswith(LAYERS) for n in names)
     on, off = win.marks["trace_on"], win.marks["trace_off"]
     windows = [e for e in program if e.name == "step.window"]
+    # no session, so no counter, before the first request
     assert len(windows) == (off.counters["host_syncs"]
-                            - on.counters["host_syncs"]) > 0
+                            - on.counters.get("host_syncs", 0)) > 0
     # every program span lies inside the benchmark's call that made it
     calls = [e for e in tr.host if e.name in ("bench.push", "bench.poke")]
     for e in program:
@@ -133,7 +143,7 @@ FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
 
 
 @pytest.fixture(scope="module")
-def chip_spans(tmp_path_factory):
+def chip_spans_path(tmp_path_factory):
     """1.27 s of the chat cell's serving loop recorded on one TPU v5e
     with the program's spans: stablelm-3b's published widths cut to 2
     layers, 8 slots x 512, paged pool, 22 decode windows and 3 prefill
@@ -141,7 +151,52 @@ def chip_spans(tmp_path_factory):
     path = tmp_path_factory.mktemp("trace") / "fixture.xplane.pb"
     with gzip.open(FIXTURE) as src, open(path, "wb") as dst:
         shutil.copyfileobj(src, dst)
-    return tm.load(str(path)), sm.load(str(path))
+    return str(path)
+
+
+@pytest.fixture(scope="module")
+def chip_spans(chip_spans_path):
+    tr = tm.load(chip_spans_path)
+    return tr, tr.spans
+
+
+def test_trace_keeps_the_spans_the_prefix_reading_kept(chip_spans_path,
+                                                       chip_spans):
+    """``Trace.spans``, found by the name pattern of the program's
+    scopes, holds exactly what the earlier reading by the three layers'
+    prefixes held: every such host event, whole, in the same order."""
+    from jax.profiler import ProfileData
+    by_prefix = []
+    for plane in ProfileData.from_file(chip_spans_path).planes:
+        if plane.name != tm.HOST_PLANE:
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(LAYERS):
+                    by_prefix.append(Event(e.name, int(e.start_ns),
+                                           int(e.start_ns
+                                               + e.duration_ns)))
+    by_prefix.sort(key=lambda e: (e.start, -e.end))
+    assert len(by_prefix) == 107
+    assert chip_spans[1] == by_prefix
+
+
+def test_host_idle_readers_on_the_recorded_trace(chip_spans):
+    """The three per-layer readers give the layers' shares of the
+    recorded span, and with the benchmark's own idle they make up no
+    more than the device's idle share."""
+    tr, _ = chip_spans
+    ctx = SimpleNamespace(trace=tr)
+    bench_dir = os.path.join(REPO, "perfbench")
+
+    def read(name):
+        return spec.metric_reader(bench_dir, name).read(ctx)
+    shares = [read(p + "host_idle_pct") for p in LAYERS]
+    assert shares == pytest.approx([0.16196023, 3.93963852, 5.19221026])
+    assert sum(shares) <= read("device.idle_pct")
+    # an untraced run has nothing to read: the metrics are left out
+    ctx = SimpleNamespace(trace=None)
+    assert [read(p + "host_idle_pct") for p in LAYERS] == [None] * 3
 
 
 def _inside(run, spans):
@@ -166,7 +221,7 @@ def test_recorded_idle_is_put_down_to_the_layers(chip_spans):
     assert tr.window_s == pytest.approx(1.266143525)
     assert len(tm.program_runs(tr, ("jit_step_k",))) == 22
     assert len(tm.program_runs(tr, ("jit_prefill_p",))) == 3
-    shares = [sm.host_idle_pct(tr, program, p) for p in sm.PREFIXES]
+    shares = [sm.host_idle_pct(tr, program, p) for p in LAYERS]
     assert shares == pytest.approx([0.16196023, 3.93963852, 5.19221026])
     idle_pct = 100.0 * (1 - tm.busy_s(tr) / tr.window_s)
     assert idle_pct == pytest.approx(70.76018084)
